@@ -23,9 +23,9 @@ The sharded section (ISSUE 3) measures the **sharded-vs-funneled** runtime
 contrast: D per-device heaps / RPC-queue shards each serving 1/D of the
 workload versus one logical state funnelling everything — first as logical
 shards in-process (the data-structure contrast), then under a REAL
-≥2-device mesh in a subprocess (forced host devices), which also asserts
-the per-device results are bit-identical to the single-heap run on a
-1-device mesh.
+≥2-device mesh of the devices the process holds (:func:`mesh_leg`), which
+also asserts the per-device results are bit-identical to the single-heap run
+on a 1-device mesh.
 
 Results are emitted as CSV rows AND returned as a perf-trajectory artifact
 dict; ``benchmarks/run.py`` (or running this module directly) writes it to
@@ -33,10 +33,7 @@ dict; ``benchmarks/run.py`` (or running this module directly) writes it to
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +53,6 @@ GRIDS = [(1, 1), (8, 4), (16, 8), (32, 16)]
 FIND_OBJ_CAPS = [256, 4096]
 FIND_OBJ_PROBES = 256
 SHARD_DEVICES = 4                 # logical shard count, in-process section
-MESH_DEVICES = 2                  # forced host devices, subprocess section
 
 
 def _grid_section(artifact: dict) -> None:
@@ -264,102 +260,103 @@ def _sharded_section(artifact: dict) -> None:
         "keep sharded at parity or better)")
 
 
-_MESH_CHILD = r"""
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.core.allocator import BalancedAllocator as BA, shard_heap
-from repro.core.expand import (expand, set_team_heap, set_team_queue,
-                               team_heap, team_id, team_queue)
-from repro.core.libc import LogRing, drain_log_lines
+MESH_GRID = (8, 4)                # teams x threads per device, mesh leg
+MESH_SPAN = 4096                  # heap words per device, mesh leg
 
-DEV = len(jax.devices())
-T, G = 8, 4
-sizes = (jnp.arange(T * G, dtype=jnp.int32).reshape(T, G) % 7) + 1
 
-def one_mesh(n_dev):
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+def mesh_leg(devices) -> dict:
+    """``malloc_grid`` + sharded log-queue flush inside ``expand(heap=True,
+    queue=True)`` over a mesh of ``devices``, against the same region on a
+    1-device mesh and a plain single heap.  Each device's pointers must be
+    the single heap's pointers (offset by its span), and the flushed
+    records must arrive device-major.  Runs in the calling process on the
+    devices it is given, so it measures whatever platform they are."""
+    from jax.sharding import PartitionSpec as P
+    from repro.core.expand import (expand, set_team_heap, set_team_queue,
+                                   team_heap, team_id, team_queue)
+    from repro.core.libc import LogRing, drain_log_lines
+    from repro.launch.mesh import make_mesh
 
-    def region():
-        st = team_heap()
-        st, ptrs = BA.malloc_grid(st, T, G, sizes)
-        set_team_heap(st)
-        set_team_queue(team_queue().log(
-            team_id(), jnp.sum(jnp.where(ptrs >= 0, ptrs, 0))
-            .astype(jnp.float32)))
-        return ptrs[None]
+    T, G = MESH_GRID
+    sizes = (jnp.arange(T * G, dtype=jnp.int32).reshape(T, G) % 7) + 1
 
-    f = jax.jit(expand(region, mesh, in_specs=(), out_specs=P("dev"),
-                       heap=True, queue=True))
+    def one_mesh(devs):
+        n_dev = len(devs)
+        mesh = make_mesh((n_dev,), ("dev",), devices=devs)
 
-    def once():
-        heap = shard_heap(BA.init(4096, 4, 2, cap=64), n_dev)
-        ring = LogRing.create_sharded(n_dev, 16)
-        return f(heap, ring)
+        def region():
+            st = team_heap()
+            st, ptrs = BA.malloc_grid(st, T, G, sizes)
+            set_team_heap(st)
+            set_team_queue(team_queue().log(
+                team_id(), jnp.sum(jnp.where(ptrs >= 0, ptrs, 0))
+                .astype(jnp.float32)))
+            return ptrs[None]
 
-    heap2, ring2, ptrs = once()                  # compile
-    jax.block_until_ready(ptrs)
-    t0 = time.perf_counter()
-    for _ in range(10):
-        _, _, p = once()
-    jax.block_until_ready(p)
-    dt = (time.perf_counter() - t0) / 10
-    drain_log_lines()
-    ring2.flush()
-    recs = drain_log_lines()
-    return np.asarray(ptrs), recs, dt
+        f = jax.jit(expand(region, mesh, in_specs=(), out_specs=P("dev"),
+                           heap=True, queue=True))
 
-ptrs_mesh, recs, dt_mesh = one_mesh(DEV)
-ptrs_one, recs_one, dt_one = one_mesh(1)
+        def once():
+            heap = shard_heap(BA.init(MESH_SPAN, 4, 2, cap=64), n_dev)
+            ring = LogRing.create_sharded(n_dev, 16)
+            return f(heap, ring)
 
-# single-heap reference: the SAME per-team request stream on a plain heap
-st = BA.init(4096, 4, 2, cap=64)
-st, ptrs_ref = jax.jit(lambda st, sz: BA.malloc_grid(st, T, G, sz))(st, sizes)
-ptrs_ref = np.asarray(ptrs_ref)
+        _, ring2, ptrs = once()                  # compile
+        jax.block_until_ready(ptrs)
+        t0 = time.perf_counter()
+        for _ in range(10):
+            _, _, p = once()
+        jax.block_until_ready(p)
+        dt = (time.perf_counter() - t0) / 10
+        drain_log_lines()
+        ring2.flush()
+        recs = drain_log_lines()
+        return np.asarray(ptrs), recs, dt
 
-span = 4096
-local_ok = all((ptrs_mesh[d] % span == ptrs_ref).all()
-               for d in range(DEV))              # team-local == single heap
-one_ok = (ptrs_one[0] == ptrs_ref).all()         # 1-device mesh bit-identical
-print(json.dumps({
-    "mesh_devices": DEV,
-    "grid": f"{T}x{G}",
-    "per_device_bit_identical_to_single_heap": bool(local_ok),
-    "one_device_mesh_bit_identical": bool(one_ok),
-    "queue_flush_records": len(recs),
-    "queue_flush_device_major": recs == sorted(recs, key=lambda r: r[0]),
-    "mesh_us_per_region": dt_mesh * 1e6,
-    "one_device_us_per_region": dt_one * 1e6,
-}))
-"""
+    devices = list(devices)
+    ptrs_mesh, recs, dt_mesh = one_mesh(devices)
+    ptrs_one, recs_one, dt_one = one_mesh(devices[:1])
+
+    # single-heap reference: the SAME per-team request stream on a plain heap
+    st = BA.init(MESH_SPAN, 4, 2, cap=64)
+    _, ptrs_ref = jax.jit(lambda st, sz: BA.malloc_grid(st, T, G, sz))(
+        st, sizes)
+    ptrs_ref = np.asarray(ptrs_ref)
+    return {
+        "mesh_devices": len(devices),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "grid": f"{T}x{G}",
+        "per_device_bit_identical_to_single_heap": bool(all(
+            (ptrs_mesh[d] % MESH_SPAN == ptrs_ref).all()
+            for d in range(len(devices)))),
+        "one_device_mesh_bit_identical": bool((ptrs_one[0] == ptrs_ref).all()),
+        "queue_flush_records": len(recs),
+        "queue_flush_device_major": recs == sorted(recs, key=lambda r: r[0]),
+        "mesh_us_per_region": dt_mesh * 1e6,
+        "one_device_us_per_region": dt_one * 1e6,
+    }
 
 
 def _mesh_section(artifact: dict) -> None:
-    """malloc_grid + sharded queue flush under a REAL >=2-device mesh
-    (forced host devices, subprocess so the device count is fresh), checking
-    per-device results bit-identical to the single-heap run.  A failing
-    child FAILS the suite — this entry is the PR's acceptance check, so it
-    must never silently degrade to a skip."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={MESH_DEVICES}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env.get("PYTHONPATH", "")])
-    out = subprocess.run([sys.executable, "-c", _MESH_CHILD],
-                         capture_output=True, text=True, timeout=560,
-                         env=env)
-    if out.returncode != 0:
+    """The mesh leg on the devices this process holds.  A mesh needs at
+    least two: with fewer the suite FAILS (this entry is an acceptance
+    check and must never degrade to a skip).  On a CPU host, give the
+    process several devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=2``."""
+    devices = jax.devices()
+    if len(devices) < 2:
         raise RuntimeError(
-            f"sharded-mesh benchmark child failed:\n{out.stderr[-2000:]}")
-    info = json.loads(out.stdout.strip().splitlines()[-1])
+            f"the sharded-mesh leg needs >= 2 devices, found {len(devices)} "
+            f"{devices[0].platform} device(s)")
+    info = mesh_leg(devices)
     emit("sharded/mesh/us_per_region", info["mesh_us_per_region"],
-         f"devices={info['mesh_devices']} "
+         f"devices={info['mesh_devices']} platform={info['platform']} "
          f"bit_identical={info['per_device_bit_identical_to_single_heap']}")
     artifact["sharded_mesh"] = info
     assert info["per_device_bit_identical_to_single_heap"], info
     assert info["one_device_mesh_bit_identical"], info
+    assert info["queue_flush_device_major"], info
 
 
 def run() -> dict:

@@ -25,6 +25,7 @@ from benchmarks import (allocator_bench, amgmk_pagerank_bench, hypterm_bench,
                         interleaved_bench, roofline, rpc_bench, rsbench_bench,
                         spec_bench, xsbench_bench)
 from benchmarks.common import write_artifact
+from repro.launch.compile_cache import use_persistent_cache
 
 SUITES = {
     "fig6_allocator": allocator_bench.run,
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
                     help="comma-separated suite prefixes")
     args = ap.parse_args(argv)
     only = args.only.split(",") if args.only else None
+    use_persistent_cache()
 
     print("name,us_per_call,derived")
     failures = 0

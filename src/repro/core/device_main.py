@@ -60,7 +60,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import events
 from repro.core import rpc as rpc_mod
 from repro.core.expand import _team_env
-from repro.core.jax_compat import shard_map
 from repro.core.rpc import REGISTRY, RpcQueue, ShardedRpcQueue, rpc_call
 
 _I32 = jax.ShapeDtypeStruct((), jnp.int32)
@@ -451,7 +450,7 @@ def _device_run_mesh(step_fn, state, n_steps, named, mesh, state_spec,
                     cond, body, (jnp.zeros((), jnp.int32), state, lq))
         return final, q.with_local(lq)
 
-    program = jax.jit(shard_map(
+    program = jax.jit(jax.shard_map(
         region, mesh=mesh, in_specs=(spec, P(axes)),
         out_specs=(spec, P(axes)), check_vma=False), **jit_kwargs)
     final, q = program(state, q0)

@@ -59,7 +59,6 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro.core.jax_compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -100,14 +99,14 @@ def team_id():
         return jnp.zeros((), jnp.int32)
     tid = jnp.zeros((), jnp.int32)
     for ax in _ENV.axes:
-        tid = tid * axis_size(ax) + lax.axis_index(ax)
+        tid = tid * lax.axis_size(ax) + lax.axis_index(ax)
     return tid
 
 
 def num_teams() -> int:
     n = 1
     for ax in _ENV.axes:
-        n *= axis_size(ax)
+        n *= lax.axis_size(ax)
     return n
 
 
@@ -295,8 +294,9 @@ def expand(fn: Callable, mesh: Mesh, in_specs, out_specs, *,
             def body(*shard_args):
                 with _team_env(axes, lanes, sanitize):
                     return fn(*shard_args)
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)(*args)
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs,
+                                 check_vma=check_vma)(*args)
 
         return wrapped
 
@@ -362,8 +362,9 @@ def expand(fn: Callable, mesh: Mesh, in_specs, out_specs, *,
                     outs.append(extra[i].with_local(_ENV.queue))
             return tuple(outs) + (out,)
 
-        return shard_map(body, mesh=mesh, in_specs=full_in,
-                         out_specs=full_out, check_vma=check_vma)(*call_args)
+        return jax.shard_map(body, mesh=mesh, in_specs=full_in,
+                             out_specs=full_out,
+                             check_vma=check_vma)(*call_args)
 
     return wrapped
 
@@ -394,7 +395,7 @@ def parallel_for(body: Callable, n: int, *arrays,
             return jax.vmap(lambda i: body(i, *arrays))(idx)
 
     spec = P(axes)
-    out = shard_map(shard_body, mesh=mesh, in_specs=(),
+    out = jax.shard_map(shard_body, mesh=mesh, in_specs=(),
                         out_specs=spec, check_vma=False)()
     return out if per * mesh.size == n else out[:n]
 

@@ -2154,21 +2154,9 @@ def _check_cpu_async_dispatch() -> None:
     three-thread cycle) and warn ONCE with the pin to apply — at
     ``RpcQueue.create`` time, so the failure mode is named where the queue
     is born instead of depending on every harness remembering the pin."""
-    if _ASYNC_DISPATCH_WARNED:
+    if _ASYNC_DISPATCH_WARNED or jax.default_backend() != "cpu":
         return
-    try:
-        if jax.default_backend() != "cpu":
-            return
-        # jax.config exposes the flag as an attribute on some versions and
-        # only through the .values mapping on others — probe both.
-        try:
-            enabled = bool(jax.config.jax_cpu_enable_async_dispatch)
-        except AttributeError:
-            enabled = bool(jax.config.values.get(
-                "jax_cpu_enable_async_dispatch", False))
-    except Exception:  # noqa: BLE001 — config probing must never break create
-        return
-    if enabled:
+    if jax.config.values["jax_cpu_enable_async_dispatch"]:
         _ASYNC_DISPATCH_WARNED.append(True)
         warnings.warn(
             "jax_cpu_enable_async_dispatch is ENABLED on the CPU backend: "

@@ -9,13 +9,11 @@ regions (the MoE dispatch and the paper-benchmark expansion path).
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from repro.core.jax_compat import axis_size
 
 
 def hierarchical_psum(x, *, intra_axis: str = "data", inter_axis: Optional[str] = "pod"):
@@ -25,10 +23,9 @@ def hierarchical_psum(x, *, intra_axis: str = "data", inter_axis: Optional[str] 
     over ``intra_axis``.  Falls back to flat psum when the tensor's leading dim
     does not divide or no inter axis exists.
     """
-    axis_env_names = _axis_names()
-    if inter_axis is None or inter_axis not in axis_env_names:
+    if inter_axis is None or not _axis_bound(inter_axis):
         return lax.psum(x, intra_axis)
-    n = axis_size(intra_axis)
+    n = lax.axis_size(intra_axis)
     if x.ndim == 0 or x.shape[0] % n != 0:
         return lax.psum(x, (intra_axis, inter_axis))
     shard = lax.psum_scatter(x, intra_axis, scatter_dimension=0, tiled=True)
@@ -36,19 +33,15 @@ def hierarchical_psum(x, *, intra_axis: str = "data", inter_axis: Optional[str] 
     return lax.all_gather(shard, intra_axis, axis=0, tiled=True)
 
 
-def _axis_names() -> Sequence[str]:
-    # jax keeps the current axis env on the trace; simplest robust probe:
+def _axis_bound(name: str) -> bool:
+    """Whether ``name`` is a mapped axis of the enclosing ``shard_map``:
+    ``lax.axis_size`` reads the live axis environment and raises
+    ``NameError`` for an unbound name."""
     try:
-        frame = jax.core.get_axis_env() if hasattr(jax.core, "get_axis_env") else None
-    except Exception:  # pragma: no cover
-        frame = None
-    if frame is not None:
-        try:
-            return tuple(frame.axis_sizes.keys())
-        except Exception:  # pragma: no cover
-            pass
-    # Fallback: report both standard names; callers guard with try/except psum.
-    return ("pod", "data", "model")
+        lax.axis_size(name)
+    except NameError:
+        return False
+    return True
 
 
 def all_to_all_tokens(x, axis: str, *, split_dim: int, concat_dim: int):

@@ -136,6 +136,41 @@ def logical_spec(*logical_axes: Optional[str],
     return P(*vals)
 
 
+def shard_map_logical(fn, in_axes: Sequence[Sequence[Optional[str]]],
+                      out_axes: Sequence[Sequence[Optional[str]]]):
+    """``fn`` run once per shard under the current mesh, its operands and
+    results split along logical axes (one name per dim, one tuple per
+    operand / result); ``fn`` itself when no mesh is installed.
+
+    For ops the partitioner cannot split, such as Mosaic kernels.  A logical
+    axis whose mesh axes do not divide every operand dim that carries it is
+    replicated everywhere, so operands and results stay consistent."""
+    def wrapped(*args):
+        mesh = _CTX.mesh
+        if mesh is None:
+            return fn(*args)
+        dims: dict = {}
+        for a, names in zip(args, in_axes):
+            for d, n in zip(a.shape, names):
+                if n is not None:
+                    dims.setdefault(n, []).append(d)
+
+        def mesh_axes(n):
+            v = _resolve(n)
+            ok = all(d % _axis_size(mesh, v) == 0 for d in dims.get(n, ()))
+            return v if ok else None
+
+        def spec(names):
+            return P(*(None if n is None else mesh_axes(n) for n in names))
+
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=tuple(spec(n) for n in in_axes),
+            out_specs=tuple(spec(n) for n in out_axes),
+            check_vma=False)(*args)
+
+    return wrapped
+
+
 def logical_sharding(*logical_axes: Optional[str],
                      shape: Optional[Sequence[int]] = None
                      ) -> Optional[NamedSharding]:

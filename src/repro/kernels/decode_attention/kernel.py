@@ -9,6 +9,9 @@ exactly once.  Grid = (B, Hkv, T/blk_t); the T axis is innermost with running
 Per-sequence valid ``lengths`` (ragged batch) are handled in-kernel: blocks
 past the length are skipped entirely (no wasted HBM reads for short
 sequences — the straggler mitigation for mixed-length decode batches).
+
+The cache is head-major, ``(B, Hkv, T, D)``, so each K/V block is a whole
+``(blk_t, D)`` tile of one kv head, as Mosaic's block tiling requires.
 """
 from __future__ import annotations
 
@@ -19,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.jax_compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -48,8 +49,8 @@ def _decode_kernel(len_ref,                     # scalar prefetch: (B,) lengths
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0, :, :]                   # (G, D)
-        k = k_ref[0, :, 0, :]                   # (blk_t, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0, :, :]                   # (blk_t, D)
+        v = v_ref[0, 0, :, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (G, blk_t)
@@ -85,7 +86,7 @@ def _decode_kernel(len_ref,                     # scalar prefetch: (B,) lengths
     static_argnames=("window", "scale", "blk_t", "interpret"))
 def decode_attention_pallas(
     q: jax.Array,            # (B, Hq, D)
-    k: jax.Array,            # (B, T, Hkv, D)
+    k: jax.Array,            # (B, Hkv, T, D)
     v: jax.Array,
     lengths: jax.Array,      # (B,) int32
     *,
@@ -95,7 +96,7 @@ def decode_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     B, Hq, D = q.shape
-    _, T, Hkv, _ = k.shape
+    _, Hkv, T, _ = k.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     blk_t = min(blk_t, T)
@@ -112,8 +113,8 @@ def decode_attention_pallas(
         grid=(B, Hkv, nt),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, it, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, blk_t, 1, D), lambda b, h, it, lens: (b, it, h, 0)),
-            pl.BlockSpec((1, blk_t, 1, D), lambda b, h, it, lens: (b, it, h, 0)),
+            pl.BlockSpec((1, 1, blk_t, D), lambda b, h, it, lens: (b, h, it, 0)),
+            pl.BlockSpec((1, 1, blk_t, D), lambda b, h, it, lens: (b, h, it, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, it, lens: (b, h, 0, 0)),
         scratch_shapes=[
@@ -126,7 +127,7 @@ def decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qg, k, v)

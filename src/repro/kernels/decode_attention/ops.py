@@ -12,7 +12,7 @@ from repro.kernels.decode_attention.ref import decode_attention_reference
 
 def decode_attention(
     q: jax.Array,            # (B, Hq, D)
-    k: jax.Array,            # (B, T, Hkv, D)
+    k: jax.Array,            # (B, Hkv, T, D)
     v: jax.Array,
     lengths: jax.Array,      # (B,) int32 valid cache length
     *,

@@ -3,7 +3,9 @@
 Tiling: grid = (B, Hq, Sq/blk_q, Sk/blk_k); the k dimension is the innermost
 ("arbitrary") axis so the online-softmax running state lives in VMEM scratch
 across k steps.  K/V blocks for query head ``h`` come from kv head ``h // G``
-(GQA), so no repeated KV is ever materialized in HBM.
+(GQA), so no repeated KV is ever materialized in HBM.  Arrays are head-major,
+``(B, H, S, D)``, so every block is a whole ``(tokens, D)`` tile of one head,
+as Mosaic's block tiling requires.
 
 VMEM working set per step (bf16 in, fp32 accum):
   q (blk_q x D) + k,v (blk_k x D each) + acc (blk_q x D fp32) + m,l
@@ -19,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.jax_compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -50,9 +50,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref,   # blocked refs
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :]                                # (blk_q, D)
-        k = k_ref[0, :, 0, :]                                # (blk_k, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0, :, :]                                # (blk_q, D)
+        k = k_ref[0, 0, :, :]                                # (blk_k, D)
+        v = v_ref[0, 0, :, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (blk_q, blk_k)
@@ -84,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref,   # blocked refs
     def _finalize():
         l = l_ref[:, 0:1]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe).astype(o_ref.dtype)
+        o_ref[0, 0, :, :] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -92,8 +92,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref,   # blocked refs
     static_argnames=("causal", "window", "q_offset", "scale",
                      "blk_q", "blk_k", "interpret"))
 def flash_attention_pallas(
-    q: jax.Array,                  # (B, Sq, Hq, D)
-    k: jax.Array,                  # (B, Sk, Hkv, D)
+    q: jax.Array,                  # (B, Hq, Sq, D)
+    k: jax.Array,                  # (B, Hkv, Sk, D)
     v: jax.Array,
     *,
     causal: bool = True,
@@ -104,8 +104,8 @@ def flash_attention_pallas(
     blk_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Sk, _ = k.shape
     assert Hq % Hkv == 0
     G = Hq // Hkv
     blk_q = min(blk_q, Sq)
@@ -123,18 +123,18 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, blk_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, blk_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, D), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -23,7 +23,7 @@ _CHUNKED_THRESHOLD = 1 << 22
 
 
 def _xla_attention(q, k, v, causal, window, q_offset, scale):
-    if q.shape[1] * k.shape[1] > _CHUNKED_THRESHOLD:
+    if q.shape[2] * k.shape[2] > _CHUNKED_THRESHOLD:
         return attention_reference_chunked(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
             scale=scale)
@@ -64,5 +64,6 @@ def flash_attention(
     q_offset: int = 0,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """GQA attention. q: (B,Sq,Hq,D); k/v: (B,Sk,Hkv,D) with Hq % Hkv == 0."""
+    """GQA attention, head-major. q: (B,Hq,Sq,D); k/v: (B,Hkv,Sk,D) with
+    Hq % Hkv == 0."""
     return _flash(q, k, v, causal, window, q_offset, scale)

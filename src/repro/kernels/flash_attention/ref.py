@@ -1,4 +1,7 @@
-"""Pure-jnp oracle for GQA flash attention (causal / windowed / offset)."""
+"""Pure-jnp oracle for GQA flash attention (causal / windowed / offset).
+
+Arrays are head-major: q (B, Hq, Sq, D), k/v (B, Hkv, Sk, D).
+"""
 from __future__ import annotations
 
 from typing import Optional
@@ -9,24 +12,24 @@ NEG_INF = -1e30
 
 
 def attention_reference(
-    q: jnp.ndarray,                 # (B, Sq, Hq, D)
-    k: jnp.ndarray,                 # (B, Sk, Hkv, D)
-    v: jnp.ndarray,                 # (B, Sk, Hkv, D)
+    q: jnp.ndarray,                 # (B, Hq, Sq, D)
+    k: jnp.ndarray,                 # (B, Hkv, Sk, D)
+    v: jnp.ndarray,                 # (B, Hkv, Sk, D)
     *,
     causal: bool = True,
     window: Optional[int] = None,   # local attention: attend to (q-window, q]
     q_offset: int = 0,              # global position of q[0] (prefill continuation)
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Sk, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
     scale = D ** -0.5 if scale is None else scale
 
-    qr = q.reshape(B, Sq, Hkv, G, D).astype(jnp.float32)
+    qr = q.reshape(B, Hkv, G, Sq, D).astype(jnp.float32)
     kf = k.astype(jnp.float32)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qr, kf) * scale
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", qr, kf) * scale
 
     qpos = q_offset + jnp.arange(Sq)[:, None]
     kpos = jnp.arange(Sk)[None, :]
@@ -41,8 +44,8 @@ def attention_reference(
     p = jnp.exp(scores - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
     p = p / jnp.maximum(l, 1e-30)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(B, Sq, Hq, D).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
+    return out.reshape(B, Hq, Sq, D).astype(q.dtype)
 
 
 def attention_reference_chunked(
@@ -61,8 +64,8 @@ def attention_reference_chunked(
     validated against it in the tests)."""
     import jax
 
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Sk, _ = k.shape
     G = Hq // Hkv
     scale = D ** -0.5 if scale is None else scale
     blk_q = min(blk_q, Sq)
@@ -72,18 +75,18 @@ def attention_reference_chunked(
                                    q_offset=q_offset, scale=scale)
     nq, nk = Sq // blk_q, Sk // blk_k
     # dtype-preserving streams: fp32 only in the (block-local) softmax state
-    qr = q.reshape(B, nq, blk_q, Hkv, G, D)
-    kr = k.reshape(B, nk, blk_k, Hkv, D)
-    vr = v.reshape(B, nk, blk_k, Hkv, D)
+    qr = q.reshape(B, Hkv, G, nq, blk_q, D)
+    kr = k.reshape(B, Hkv, nk, blk_k, D)
+    vr = v.reshape(B, Hkv, nk, blk_k, D)
 
     def q_block(iq):
-        qb = qr[:, iq]                                    # (B, blk_q, Hkv, G, D)
+        qb = qr[:, :, :, iq]                              # (B, Hkv, G, blk_q, D)
         qpos = q_offset + iq * blk_q + jnp.arange(blk_q)
 
         def k_step(carry, ik):
             m, l, acc = carry
-            kb, vb = kr[:, ik], vr[:, ik]
-            s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, kb,
+            kb, vb = kr[:, :, ik], vr[:, :, ik]
+            s = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kb,
                            preferred_element_type=jnp.float32) * scale
             kpos = ik * blk_k + jnp.arange(blk_k)
             mask = jnp.ones((blk_q, blk_k), bool)
@@ -97,7 +100,7 @@ def attention_reference_chunked(
             p = jnp.exp(s - m_new)
             l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc = acc * alpha + jnp.einsum(
-                "bhgqk,bkhd->bhgqd", p.astype(vb.dtype), vb,
+                "bhgqk,bhkd->bhgqd", p.astype(vb.dtype), vb,
                 preferred_element_type=jnp.float32)
             return (m_new, l, acc), None
 
@@ -105,9 +108,8 @@ def attention_reference_chunked(
         l0 = jnp.zeros((B, Hkv, G, blk_q, 1))
         a0 = jnp.zeros((B, Hkv, G, blk_q, D))
         (m, l, acc), _ = jax.lax.scan(k_step, (m0, l0, a0), jnp.arange(nk))
-        out = acc / jnp.maximum(l, 1e-30)                  # (B,Hkv,G,blk_q,D)
-        return jnp.transpose(out, (0, 3, 1, 2, 4))          # (B,blk_q,Hkv,G,D)
+        return acc / jnp.maximum(l, 1e-30)                 # (B,Hkv,G,blk_q,D)
 
-    out = jax.lax.map(q_block, jnp.arange(nq))              # (nq,B,blk_q,...)
-    out = jnp.moveaxis(out, 0, 1).reshape(B, Sq, Hq, D)
+    out = jax.lax.map(q_block, jnp.arange(nq))              # (nq,B,Hkv,G,blk_q,D)
+    out = jnp.moveaxis(out, 0, 3).reshape(B, Hq, Sq, D)
     return out.astype(q.dtype)

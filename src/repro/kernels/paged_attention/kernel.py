@@ -9,6 +9,10 @@ XLA reference path has to do, and what this kernel deletes).
 Grid = (B, Hkv, MAXP); online softmax carried in VMEM scratch across the page
 axis; blocks past ``lengths[b]`` are skipped entirely, so HBM traffic per
 step is ceil(len/page) pages — the roofline minimum.
+
+Pages are stored head-major, ``(NP, Hkv, page, D)``, so each K/V block is a
+whole ``(page, D)`` tile of one kv head: Mosaic requires a block's last two
+dimensions to be (8, 128)-divisible or to span the array's.
 """
 from __future__ import annotations
 
@@ -19,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.jax_compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -48,8 +50,8 @@ def _paged_kernel(pt_ref, len_ref,               # scalar prefetch
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0, :, :]                    # (G, D)
-        k = k_ref[0, :, 0, :]                    # (page, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0, :, :]                    # (page, D)
+        v = v_ref[0, 0, :, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -81,7 +83,7 @@ def _paged_kernel(pt_ref, len_ref,               # scalar prefetch
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
 def paged_decode_attention_pallas(
     q: jax.Array,              # (B, Hq, D)
-    k_pages: jax.Array,        # (NP, page, Hkv, D)
+    k_pages: jax.Array,        # (NP, Hkv, page, D)
     v_pages: jax.Array,
     page_table: jax.Array,     # (B, MAXP) int32
     lengths: jax.Array,        # (B,) int32
@@ -91,7 +93,7 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     B, Hq, D = q.shape
-    NP, page, Hkv, _ = k_pages.shape
+    NP, Hkv, page, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = Hq // Hkv
     scale = D ** -0.5 if scale is None else scale
@@ -105,10 +107,10 @@ def paged_decode_attention_pallas(
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, it, pt, ln: (b, h, 0, 0)),
             # the page table drives which page streams into VMEM:
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, it, pt, ln: (pt[b, it], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, it, pt, ln: (pt[b, it], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, it, pt, ln: (pt[b, it], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, it, pt, ln: (pt[b, it], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, it, pt, ln: (b, h, 0, 0)),
@@ -122,7 +124,7 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.clip(page_table, 0, NP - 1), lengths, qg, k_pages, v_pages)

@@ -12,7 +12,7 @@ from repro.kernels.paged_attention.ref import paged_decode_attention_reference
 
 def paged_decode_attention(
     q: jax.Array,              # (B, Hq, D)
-    k_pages: jax.Array,        # (NP, page, Hkv, D)
+    k_pages: jax.Array,        # (NP, Hkv, page, D)
     v_pages: jax.Array,
     page_table: jax.Array,     # (B, MAXP)
     lengths: jax.Array,        # (B,)

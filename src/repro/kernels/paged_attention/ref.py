@@ -10,7 +10,7 @@ from repro.kernels.decode_attention.ref import decode_attention_reference
 
 def paged_decode_attention_reference(
     q: jnp.ndarray,            # (B, Hq, D)
-    k_pages: jnp.ndarray,      # (NP, page, Hkv, D)
+    k_pages: jnp.ndarray,      # (NP, Hkv, page, D)
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,   # (B, MAXP) int32 page ids (garbage past length)
     lengths: jnp.ndarray,      # (B,) int32
@@ -19,10 +19,14 @@ def paged_decode_attention_reference(
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     B = q.shape[0]
-    NP, page, Hkv, D = k_pages.shape
+    NP, Hkv, page, D = k_pages.shape
     maxp = page_table.shape[1]
     safe = jnp.clip(page_table, 0, NP - 1)
-    k = k_pages[safe].reshape(B, maxp * page, Hkv, D)
-    v = v_pages[safe].reshape(B, maxp * page, Hkv, D)
+
+    def gather(pages):                   # (B, maxp, Hkv, page, D) -> (B, Hkv, T, D)
+        g = jnp.swapaxes(pages[safe], 1, 2)
+        return g.reshape(B, Hkv, maxp * page, D)
+
+    k, v = gather(k_pages), gather(v_pages)
     return decode_attention_reference(q, k, v, lengths, window=window,
                                       scale=scale)

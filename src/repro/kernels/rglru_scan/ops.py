@@ -1,20 +1,29 @@
 """Public linear-scan op with TPU/CPU dispatch and recompute VJP."""
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import shard_map_logical
 from repro.kernels import use_pallas, interpret_mode
 from repro.kernels.rglru_scan.kernel import linear_scan_pallas
 from repro.kernels.rglru_scan.ref import linear_scan_reference
 
 
+# channel-independent: under a mesh the Mosaic kernel runs per shard
+_LSCAN_IN = (("batch", None, "lru"), ("batch", None, "lru"))
+_LSCAN_OUT = (("batch", None, "lru"), ("batch", "lru"))
+
+
 @jax.custom_vjp
 def _lscan(a, b):
     if use_pallas():
-        return linear_scan_pallas(a, b, interpret=interpret_mode())
+        kernel = functools.partial(linear_scan_pallas,
+                                   interpret=interpret_mode())
+        return shard_map_logical(kernel, _LSCAN_IN, _LSCAN_OUT)(a, b)
     return linear_scan_reference(a, b)
 
 

@@ -1,14 +1,20 @@
 """Pallas TPU kernel for the mamba2 SSD chunked scan.
 
-Grid = (B, S/Q) with the chunk axis innermost ("arbitrary"): the running SSM
-state (H, P, N fp32) lives in VMEM scratch and is carried across chunks, so
-HBM traffic is exactly one read of (x, dt, B, C) and one write of y — the
-scan itself never touches HBM.  Within a chunk the intra-chunk term is the
-masked-quadratic duality form, which maps onto the MXU as (Q x N)·(N x Q) and
-(Q x Q)·(Q x P) matmuls per head.
+Grid = (B, H, S/Q) with the chunk axis innermost ("arbitrary"): each head's
+running SSM state (P, N fp32) lives in VMEM scratch and is carried across
+chunks, so HBM traffic is exactly one read of (x, dt, B, C) and one write of
+y — the scan itself never touches HBM.  Within a chunk the intra-chunk term
+is the masked-quadratic duality form, which maps onto the MXU as
+(Q x N)·(N x Q) and (Q x Q)·(Q x P) matmuls.
 
-VMEM: state 24x64x128x4 = 0.75 MB (mamba2-130m) + chunk blocks (Q=256:
-x 0.75 MB bf16) — comfortably inside 16 MB with double buffering.
+Mosaic has no cumsum, so the in-chunk prefix sum of dt*A is a masked
+reduction against the lower-triangular ones matrix, done on the VPU in fp32.
+The same trick (a reduction against the identity) turns a (1, Q) row into
+a (Q, 1) column.  The wrapper lays x out head-major and dt as rows, so every
+block's last two dimensions tile the array's.
+
+VMEM: state 64x128x4 = 32 KB per head (mamba2-130m) + chunk blocks and a few
+(Q, Q) fp32 temporaries (Q=256: 256 KB each) — well inside the scoped limit.
 """
 from __future__ import annotations
 
@@ -20,63 +26,62 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.jax_compat import CompilerParams as _CompilerParams
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
+def _ssd_kernel(a_ref, d_ref,                    # scalar prefetch: (H,) each
+                x_ref, dt_ref, b_ref, c_ref,
                 y_ref, fs_ref,
                 state_ref,
-                *, nc: int, Q: int, H: int, P: int, N: int):
-    ic = pl.program_id(1)
+                *, nc: int, Q: int):
+    h = pl.program_id(1)
+    ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0].astype(jnp.float32)            # (Q, H, P)
-    dt = dt_ref[0].astype(jnp.float32)          # (Q, H)
-    A = a_ref[0].astype(jnp.float32)            # (H,)
+    x = x_ref[0, 0].astype(jnp.float32)         # (Q, P)
+    dt_row = dt_ref[0, 0].astype(jnp.float32)   # (1, Q)
     B = b_ref[0].astype(jnp.float32)            # (Q, N)
     C = c_ref[0].astype(jnp.float32)            # (Q, N)
-    D = d_ref[0].astype(jnp.float32)            # (H,)
 
-    da = dt * A[None, :]                        # (Q, H)
-    cs = jnp.cumsum(da, axis=0)                 # (Q, H)
+    i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    tril, eye = j <= i, i == j
 
-    # intra-chunk masked quadratic term
+    def to_col(row):                            # (1, Q) -> (Q, 1)
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    da_row = dt_row * a_ref[h]
+    cs_col = jnp.sum(jnp.where(tril, da_row, 0.0), axis=1,
+                     keepdims=True)                               # (Q, 1)
+    cs_row = jnp.sum(jnp.where(eye, cs_col, 0.0), axis=0,
+                     keepdims=True)                               # (1, Q)
+    cs_last = jnp.sum(da_row, axis=1, keepdims=True)              # (1, 1)
+    dt_col = to_col(dt_row)
+
+    # intra-chunk masked quadratic term; mask BEFORE exp (j > i overflows)
     G = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (Q, Q) i,j
-    seg = jnp.exp(cs[:, None, :] - cs[None, :, :])                # (Q, Q, H)
-    iota_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    iota_j = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    tril = iota_j <= iota_i
-    seg = jnp.where(tril[:, :, None], seg, 0.0)
-    M = G[:, :, None] * seg * dt[None, :, :]                      # (Q, Q, H)
-    # y_intra[i,h,p] = sum_j M[i,j,h] * x[j,h,p]
-    y_intra = jnp.einsum("ijh,jhp->ihp", M, x,
-                         preferred_element_type=jnp.float32)
+    seg = jnp.exp(jnp.where(tril, cs_col - cs_row, -1e30))
+    M = G * seg * dt_row                                          # weight j->i
+    y_intra = jnp.dot(M, x, preferred_element_type=jnp.float32)   # (Q, P)
 
-    # inter-chunk: contribution of carried state
-    state = state_ref[...]                                        # (H, P, N)
-    # y_inter[i,h,p] = exp(cs[i,h]) * sum_n C[i,n] * state[h,p,n]
-    cstate = jnp.einsum("in,hpn->ihp", C, state,
-                        preferred_element_type=jnp.float32)
-    y_inter = jnp.exp(cs)[:, :, None] * cstate
+    # inter-chunk: contribution of the carried state
+    state = state_ref[...]                                        # (P, N)
+    cstate = jax.lax.dot_general(C, state, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)  # (Q, P)
+    y = y_intra + jnp.exp(cs_col) * cstate + d_ref[h] * x
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    y = y_intra + y_inter + D[None, :, None] * x
-    y_ref[0] = y.astype(y_ref.dtype)
-
-    # state update: decay whole chunk + add chunk contribution
-    decay_to_end = jnp.exp(cs[-1:, :] - cs)                       # (Q, H)
-    w = decay_to_end * dt                                          # (Q, H)
-    S_c = jnp.einsum("qh,qhp,qn->hpn", w, x, B,
-                     preferred_element_type=jnp.float32)
-    T_c = jnp.exp(cs[-1, :])                                       # (H,)
-    state_ref[...] = T_c[:, None, None] * state + S_c
+    # state update: decay the whole chunk, add the chunk's contribution
+    w = jnp.exp(cs_last - cs_col) * dt_col                        # (Q, 1)
+    S_c = jax.lax.dot_general(x * w, B, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)  # (P, N)
+    state_ref[...] = jnp.exp(cs_last) * state + S_c
 
     @pl.when(ic == nc - 1)
     def _emit_state():
-        fs_ref[0] = state_ref[...]
+        fs_ref[0, 0] = state_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -97,32 +102,33 @@ def ssd_scan_pallas(
     assert S % Q == 0, (S, Q)
     nc = S // Q
 
-    kernel = functools.partial(_ssd_kernel, nc=nc, Q=Q, H=H, P=P, N=N)
-    a2 = A.reshape(1, H)
-    d2 = D.reshape(1, H)
-
-    y, fs = pl.pallas_call(
-        kernel,
-        grid=(bsz, nc),
+    xh = jnp.swapaxes(x, 1, 2)                              # (B, H, S, P)
+    dt_rows = jnp.swapaxes(dt, 1, 2)[:, :, None, :]         # (B, H, 1, S)
+    kernel = functools.partial(_ssd_kernel, nc=nc, Q=Q)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(bsz, H, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, H, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, Q, H), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, H), lambda b, c: (0, 0)),
-            pl.BlockSpec((1, Q, N), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, Q, N), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, H), lambda b, c: (0, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c, a, d: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c, a, d: (b, h, 0, c)),
+            pl.BlockSpec((1, Q, N), lambda b, h, c, a, d: (b, c, 0)),
+            pl.BlockSpec((1, Q, N), lambda b, h, c, a, d: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, H, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, H, P, N), lambda b, c: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c, a, d: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, P, N), lambda b, h, c, a, d: (b, h, 0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+    )
+    y, fs = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((bsz, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((bsz, H, P, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, a2, B, C, d2)
-    return y, fs
+    )(A.astype(jnp.float32), D.astype(jnp.float32), xh, dt_rows, B, C)
+    return jnp.swapaxes(y, 1, 2), fs

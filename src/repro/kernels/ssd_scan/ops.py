@@ -8,16 +8,28 @@ import jax
 
 import jax.numpy as jnp
 
+from repro.distributed.sharding import shard_map_logical
 from repro.kernels import use_pallas, interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.kernels.ssd_scan.ref import ssd_scan_reference, ssd_decode_reference
 
 
+# per-batch, per-head independent: a Mosaic kernel cannot be partitioned by
+# GSPMD, so under a mesh it runs per shard of batch and heads
+_SSD_IN = (("batch", None, "ssm_heads", None), ("batch", None, "ssm_heads"),
+           ("ssm_heads",), ("batch", None, None), ("batch", None, None),
+           ("ssm_heads",))
+_SSD_OUT = (("batch", None, "ssm_heads", None),
+            ("batch", "ssm_heads", None, None))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _ssd(x, dt, A, B, C, D, chunk):
     if use_pallas():
-        return ssd_scan_pallas(x, dt, A, B, C, D, chunk=chunk,
-                               interpret=interpret_mode())
+        kernel = functools.partial(ssd_scan_pallas, chunk=chunk,
+                                   interpret=interpret_mode())
+        return shard_map_logical(kernel, _SSD_IN, _SSD_OUT)(
+            x, dt, A, B, C, D)
     return ssd_scan_reference(x, dt, A, B, C, D, chunk=chunk)
 
 

@@ -1,7 +1,12 @@
-"""Serving driver: paged-KV continuous-batching engine on a small model.
+"""Serving driver: paged-KV continuous-batching engine.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b \
       --requests 8 --max-new 12
+
+``--preset tiny`` (the default) serves a 4-layer, d_model 128 cut of the
+architecture, small enough for a CPU; ``--preset full`` serves it at its
+registered size (llama3.2-3b: 28 layers, d_model 3072, bf16 parameters of
+about 7.2 GB), which needs an accelerator.  Weights are random from a seed.
 
 Durable-artifact round trip (cold-start AOT serving):
 
@@ -21,8 +26,8 @@ import time
 
 import jax
 
-from repro.configs import get_config
-from repro.launch.train import tiny_preset
+from repro.launch.compile_cache import use_persistent_cache
+from repro.launch.train import preset_config
 from repro.models.model_zoo import build_model
 from repro.serving.engine import ServingEngine
 
@@ -48,6 +53,7 @@ def _serve_traffic(engine: ServingEngine, cfg, requests: int, max_new: int,
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3.2-3b")
+    ap.add_argument("--preset", default="tiny", choices=["tiny", "full"])
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--batch-slots", type=int, default=4)
@@ -60,13 +66,12 @@ def main(argv=None):
                          "serve from its serialized step (no model build, "
                          "no retrace)")
     args = ap.parse_args(argv)
+    use_persistent_cache()
 
     if args.from_artifact:
         with open(os.path.join(args.from_artifact, "engine.json")) as f:
             meta = json.load(f)
-        cfg = get_config(meta["arch"])
-        if meta.get("tiny_preset"):
-            cfg = tiny_preset(cfg)
+        cfg = preset_config(meta["arch"], meta["preset"])
         engine = ServingEngine.from_artifact(args.from_artifact, cfg)
         assert engine._step_source == "artifact"
         print(f"[serve] cold start from {args.from_artifact} "
@@ -74,7 +79,7 @@ def main(argv=None):
         _serve_traffic(engine, cfg, args.requests, args.max_new, "serve")
         return
 
-    cfg = tiny_preset(get_config(args.arch))
+    cfg = preset_config(args.arch, args.preset)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     engine = ServingEngine(model, params, batch_slots=args.batch_slots,
@@ -84,7 +89,7 @@ def main(argv=None):
     if args.export_artifact:
         engine.export_artifact(
             args.export_artifact,
-            extra_meta={"arch": args.arch, "tiny_preset": True})
+            extra_meta={"arch": args.arch, "preset": args.preset})
         print(f"[serve] artifact exported to {args.export_artifact}")
 
 

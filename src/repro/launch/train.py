@@ -30,6 +30,7 @@ from repro.core.libc import LogRing
 from repro.data.pipeline import SyntheticLM
 from repro.core.libc import rand_init
 from repro.distributed.sharding import ShardingCtx
+from repro.launch.compile_cache import use_persistent_cache
 from repro.models.common import split_params
 from repro.models.model_zoo import build_model
 from repro.train.optimizer import OptConfig, adamw_init
@@ -43,13 +44,17 @@ def tiny_preset(cfg: ModelConfig) -> ModelConfig:
         d_ff=256, vocab_size=512)
 
 
+def preset_config(arch: str, preset: str) -> ModelConfig:
+    """``full``: the registered configuration; ``tiny``: a CPU-sized cut."""
+    cfg = get_config(arch)
+    return tiny_preset(cfg) if preset == "tiny" else cfg
+
+
 def run(arch: str, *, preset: str = "tiny", steps: int = 50, batch: int = 8,
         seq_len: int = 64, lr: float = 1e-3, ckpt_dir: Optional[str] = None,
         ckpt_every: int = 0, log_every: int = 10, resume: bool = False,
         mesh=None, rules=None) -> Dict[str, Any]:
-    cfg = get_config(arch)
-    if preset == "tiny":
-        cfg = tiny_preset(cfg)
+    cfg = preset_config(arch, preset)
     model = build_model(cfg)
     data = SyntheticLM(cfg.vocab_size, seq_len, batch)
 
@@ -132,6 +137,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    use_persistent_cache()
     out = run(args.arch, preset=args.preset, steps=args.steps,
               batch=args.batch, seq_len=args.seq_len, lr=args.lr,
               ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -1,4 +1,9 @@
-"""GQA multi-head attention: train/prefill path + KV-cache decode path."""
+"""GQA multi-head attention: train/prefill path + KV-cache decode path.
+
+Projections are (B, S, H, hd); the attention kernels and the KV caches are
+head-major, (B, H, S, hd), so each kernel block is a whole (tokens, hd) tile
+of one head.
+"""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -83,10 +88,11 @@ def attn_apply(
     window: Optional[int] = None,
     return_kv: bool = False,
 ):
-    """Full-sequence attention (training / prefill)."""
-    q, k, v = _project_qkv(p, x, cfg, angles)
+    """Full-sequence attention (training / prefill).  ``return_kv`` also
+    returns the head-major (B, Hkv, S, hd) K/V that fill a decode cache."""
+    q, k, v = (jnp.swapaxes(t, 1, 2) for t in _project_qkv(p, x, cfg, angles))
     out = flash_attention(q, k, v, causal=causal, window=window)
-    out = _mask_heads(out, cfg)
+    out = _mask_heads(jnp.swapaxes(out, 1, 2), cfg)
     out = wlc(out, "batch", "seq", "heads", "head_dim")
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].value.astype(x.dtype))
     out = wlc(out, "batch", "seq", "embed")
@@ -97,15 +103,16 @@ def attn_apply(
 
 def cross_kv(p, enc_out: jax.Array, cfg: ModelConfig
              ) -> Tuple[jax.Array, jax.Array]:
-    """Project encoder output to cross-attention K/V (cached for decode)."""
+    """Project encoder output to head-major cross-attention K/V
+    (B, Hkv, S, hd), cached for decode."""
     dt = enc_out.dtype
-    k = jnp.einsum("bsd,dhk->bshk", enc_out, p["wk"].value.astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", enc_out, p["wv"].value.astype(dt))
+    k = jnp.einsum("bsd,dhk->bhsk", enc_out, p["wk"].value.astype(dt))
+    v = jnp.einsum("bsd,dhk->bhsk", enc_out, p["wv"].value.astype(dt))
     if cfg.qkv_bias:
-        k = k + p["bk"].value.astype(dt)
-        v = v + p["bv"].value.astype(dt)
-    k = wlc(k, "batch", "seq", "kv_heads", "head_dim")
-    v = wlc(v, "batch", "seq", "kv_heads", "head_dim")
+        k = k + p["bk"].value.astype(dt)[:, None]
+        v = v + p["bv"].value.astype(dt)[:, None]
+    k = wlc(k, "batch", "kv_heads", "seq", "head_dim")
+    v = wlc(v, "batch", "kv_heads", "seq", "head_dim")
     return k, v
 
 
@@ -118,8 +125,8 @@ def cross_attn_apply(p, xq: jax.Array, kv: Tuple[jax.Array, jax.Array],
         q = q + p["bq"].value.astype(dt)
     q = wlc(q, "batch", "seq", "heads", "head_dim")
     k, v = kv
-    out = flash_attention(q, k, v, causal=False)
-    out = _mask_heads(out, cfg)
+    out = flash_attention(jnp.swapaxes(q, 1, 2), k, v, causal=False)
+    out = _mask_heads(jnp.swapaxes(out, 1, 2), cfg)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].value.astype(dt))
     return wlc(out, "batch", "seq", "embed")
 
@@ -140,7 +147,7 @@ def cross_attn_decode(p, x: jax.Array, kv: Tuple[jax.Array, jax.Array],
 
 def attn_decode(
     p, x: jax.Array, cfg: ModelConfig, *,
-    k_cache: jax.Array,            # (B, T, Hkv, hd)
+    k_cache: jax.Array,            # (B, Hkv, T, hd)
     v_cache: jax.Array,
     lengths: jax.Array,            # (B,) current length BEFORE this token
     angles: Optional[jax.Array],   # (B, 1, hd//2)
@@ -159,10 +166,10 @@ def attn_decode(
     q, k, v = _project_qkv(p, x, cfg, angles)      # S == 1
     idx = jnp.arange(B)
     wp = lengths if write_pos is None else write_pos
-    k_cache = k_cache.at[idx, wp].set(k[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[idx, wp].set(v[:, 0].astype(v_cache.dtype))
-    k_cache = wlc(k_cache, "batch", "seq_kv", None, "head_dim")
-    v_cache = wlc(v_cache, "batch", "seq_kv", None, "head_dim")
+    k_cache = k_cache.at[idx, :, wp].set(k[:, 0].astype(k_cache.dtype))
+    v_cache = v_cache.at[idx, :, wp].set(v[:, 0].astype(v_cache.dtype))
+    k_cache = wlc(k_cache, "batch", None, "seq_kv", "head_dim")
+    v_cache = wlc(v_cache, "batch", None, "seq_kv", "head_dim")
     vl = lengths + 1 if valid_len is None else valid_len
     out = decode_attention(q[:, 0], k_cache, v_cache, vl, window=window)
     out = _mask_heads(out[:, None], cfg)            # (B, 1, Hq, hd)

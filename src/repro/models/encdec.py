@@ -121,18 +121,18 @@ def encdec_init_cache(cfg: ModelConfig, batch: int, max_len: int,
     n_dec = cfg.decoder_layers
     enc_len = enc_len if enc_len is not None else max_len
     vals = {
-        "k": jnp.zeros((n_dec, batch, max_len, cfg.num_kv_heads, hd), cdt),
-        "v": jnp.zeros((n_dec, batch, max_len, cfg.num_kv_heads, hd), cdt),
-        "xk": jnp.zeros((n_dec, batch, enc_len, cfg.num_kv_heads, hd), cdt),
-        "xv": jnp.zeros((n_dec, batch, enc_len, cfg.num_kv_heads, hd), cdt),
+        "k": jnp.zeros((n_dec, batch, cfg.num_kv_heads, max_len, hd), cdt),
+        "v": jnp.zeros((n_dec, batch, cfg.num_kv_heads, max_len, hd), cdt),
+        "xk": jnp.zeros((n_dec, batch, cfg.num_kv_heads, enc_len, hd), cdt),
+        "xv": jnp.zeros((n_dec, batch, cfg.num_kv_heads, enc_len, hd), cdt),
         "enc_lens": jnp.zeros((batch,), jnp.int32),
         "lengths": jnp.zeros((batch,), jnp.int32),
     }
     axes = {
-        "k": ("stack", "batch", "seq_kv", None, "head_dim"),
-        "v": ("stack", "batch", "seq_kv", None, "head_dim"),
-        "xk": ("stack", "batch", "seq_kv", None, "head_dim"),
-        "xv": ("stack", "batch", "seq_kv", None, "head_dim"),
+        "k": ("stack", "batch", None, "seq_kv", "head_dim"),
+        "v": ("stack", "batch", None, "seq_kv", "head_dim"),
+        "xk": ("stack", "batch", None, "seq_kv", "head_dim"),
+        "xv": ("stack", "batch", None, "seq_kv", "head_dim"),
         "enc_lens": ("batch",),
         "lengths": ("batch",),
     }

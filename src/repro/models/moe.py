@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.jax_compat import axis_size, shard_map
 from repro.distributed.sharding import current_mesh, with_logical_constraint as wlc
 from repro.models.common import Param, normal
 
@@ -108,7 +107,7 @@ def _moe_local(x_loc, router_w, wg, wu, wo, *, E: int, K: int, C: int,
     buf = buf.reshape(E, C, d)
 
     # ship to expert shards, compute, ship back
-    ep = axis_size(ep_axis)
+    ep = lax.axis_size(ep_axis)
     recv = lax.all_to_all(buf, ep_axis, split_axis=0, concat_axis=1, tiled=True)
     out = _expert_ffn(recv, wg, wu, wo)                  # (E/ep, C*ep, d)
     send = lax.all_to_all(out, ep_axis, split_axis=1, concat_axis=0, tiled=True)
@@ -133,7 +132,7 @@ def _moe_local_replicated(x_row, router_w, wg, wu, wo, *, E: int, K: int,
     psum over the expert axis combines per-token outputs.  No all_to_all —
     right for tiny per-step token counts where dispatch latency dominates."""
     T_row, d = x_row.shape
-    ep = axis_size(ep_axis)
+    ep = lax.axis_size(ep_axis)
     my = lax.axis_index(ep_axis)
     E_loc = E // ep
     topv, topi, probs = _route(x_row, router_w, K)
@@ -189,7 +188,7 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
             x_flat = wlc(x.reshape(T, d), "batch", None)
             body = functools.partial(_moe_local_replicated, E=E, K=K, C=C,
                                      ep_axis="model")
-            y_flat, aux_all = shard_map(
+            y_flat, aux_all = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(dp_axes, None), P(None, None),
                           P("model", None, None), P("model", None, None),
@@ -213,7 +212,7 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
 
     x_flat = wlc(x.reshape(T, d), "tokens", None)
     body = functools.partial(_moe_local, E=E, K=K, C=C, ep_axis="model")
-    y_flat, aux_all, dropped_all = shard_map(
+    y_flat, aux_all, dropped_all = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(all_axes, None), P(None, None),
                   P("model", None, None), P("model", None, None),

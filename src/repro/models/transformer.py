@@ -341,26 +341,26 @@ def lm_init_cache(cfg: ModelConfig, batch: int, max_len: int):
         vals = {
             "rec": jax.tree.map(
                 lambda v: jnp.broadcast_to(v, (n_rec,) + v.shape), rec_one),
-            "k": jnp.zeros((n_att, batch, w, cfg.num_kv_heads, hd), cdt),
-            "v": jnp.zeros((n_att, batch, w, cfg.num_kv_heads, hd), cdt),
+            "k": jnp.zeros((n_att, batch, cfg.num_kv_heads, w, hd), cdt),
+            "v": jnp.zeros((n_att, batch, cfg.num_kv_heads, w, hd), cdt),
             "lengths": jnp.zeros((batch,), jnp.int32),
         }
         axes = {
             "rec": jax.tree.map(lambda a: ("stack",) + a, rglru_cache_axes(cfg),
                                 is_leaf=lambda v: isinstance(v, tuple)),
-            "k": ("stack", "batch", None, "kv_heads", "head_dim"),
-            "v": ("stack", "batch", None, "kv_heads", "head_dim"),
+            "k": ("stack", "batch", "kv_heads", None, "head_dim"),
+            "v": ("stack", "batch", "kv_heads", None, "head_dim"),
             "lengths": ("batch",),
         }
         return vals, axes
     vals = {
-        "k": jnp.zeros((cfg.num_layers, batch, max_len, cfg.num_kv_heads, hd), cdt),
-        "v": jnp.zeros((cfg.num_layers, batch, max_len, cfg.num_kv_heads, hd), cdt),
+        "k": jnp.zeros((cfg.num_layers, batch, cfg.num_kv_heads, max_len, hd), cdt),
+        "v": jnp.zeros((cfg.num_layers, batch, cfg.num_kv_heads, max_len, hd), cdt),
         "lengths": jnp.zeros((batch,), jnp.int32),
     }
     axes = {
-        "k": ("stack", "batch", "seq_kv", None, "head_dim"),
-        "v": ("stack", "batch", "seq_kv", None, "head_dim"),
+        "k": ("stack", "batch", None, "seq_kv", "head_dim"),
+        "v": ("stack", "batch", None, "seq_kv", "head_dim"),
         "lengths": ("batch",),
     }
     return vals, axes
@@ -371,13 +371,13 @@ def lm_init_cache(cfg: ModelConfig, batch: int, max_len: int):
 # ---------------------------------------------------------------------------
 
 def _ring_fill(cache_kv: jax.Array, kv: jax.Array, w: int) -> jax.Array:
-    """Write the last ``w`` positions of kv (B,S,H,D) into a ring cache
-    (B,w,H,D) at ring indices pos % w."""
-    S = kv.shape[1]
+    """Write the last ``w`` positions of kv (B,H,S,D) into a ring cache
+    (B,H,w,D) at ring indices pos % w."""
+    S = kv.shape[2]
     n = min(S, w)
-    tail = kv[:, S - n:]
+    tail = kv[:, :, S - n:]
     idx = (jnp.arange(S - n, S) % w).astype(jnp.int32)
-    return cache_kv.at[:, idx].set(tail.astype(cache_kv.dtype))
+    return cache_kv.at[:, :, idx].set(tail.astype(cache_kv.dtype))
 
 
 def lm_prefill(params, batch, cfg: ModelConfig, max_len: int
@@ -405,7 +405,7 @@ def lm_prefill(params, batch, cfg: ModelConfig, max_len: int
         kinds = hybrid_layer_kinds(cfg)
         rec_vals, rec_axes = split_params(params["rec_layers"])
         att_vals, att_axes = split_params(params["attn_layers"])
-        w = cache["k"].shape[2]
+        w = cache["k"].shape[3]
         new_rec, new_k, new_v = [], [], []
         ri = ai = 0
         for kind in kinds:
@@ -456,9 +456,9 @@ def lm_prefill(params, batch, cfg: ModelConfig, max_len: int
 
         x, (ks, vs) = lax.scan(body, x, stacked_vals)
         pad = max_len - S
-        kc = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
+        kc = jnp.pad(ks, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
                      ).astype(cache["k"].dtype)
-        vc = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
+        vc = jnp.pad(vs, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
                      ).astype(cache["v"].dtype)
         cache = {"k": kc, "v": vc, "lengths": lengths}
 
@@ -540,7 +540,7 @@ def _hybrid_decode(params, cache, x, angles, cfg: ModelConfig):
     rec_vals, rec_axes = split_params(params["rec_layers"])
     att_vals, att_axes = split_params(params["attn_layers"])
     lengths = cache["lengths"]
-    w = cache["k"].shape[2]
+    w = cache["k"].shape[3]
     ring = lengths % w
     eff_len = jnp.minimum(lengths + 1, w)
 

@@ -72,7 +72,7 @@ def paged_decode_step(params, kv: PagedKV, tokens: jax.Array,
         layer = _slice_layer(stacked_axes, layer_vals)
         h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
         q, k, v = _project_qkv(layer["attn"], h, cfg, angles)
-        kv = _write_layer(kv, li, k[:, 0], v[:, 0], active)
+        kv = kvcache.write_token_kv(kv, li, k[:, 0], v[:, 0], active)
         a = kvcache.paged_attend(kv, li, q[:, 0])
         a = _mask_heads(a[:, None], cfg)[:, 0]
         a = jnp.einsum("bhk,hkd->bd", a, layer["attn"]["wo"].value.astype(x.dtype))
@@ -92,22 +92,6 @@ def paged_decode_step(params, kv: PagedKV, tokens: jax.Array,
         else params["lm_head"].value
     logits = common.lm_logits(x, head, cfg)[:, 0]
     return logits, kv
-
-
-def _write_layer(kv: PagedKV, layer, k, v, active) -> PagedKV:
-    """Dynamic-layer-index variant of kvcache.write_token_kv (scan-safe)."""
-    B = kv.lengths.shape[0]
-    pos = kv.lengths
-    pidx = jnp.minimum(pos // kv.page_size, kv.page_table.shape[1] - 1)
-    page = kv.page_table[jnp.arange(B), pidx]
-    off = pos % kv.page_size
-    NP = kv.k_pages.shape[1]
-    page = jnp.where(active, page, NP)           # OOB scatter -> dropped
-    k_pages = kv.k_pages.at[layer, page, off, :, :].set(
-        k.astype(kv.k_pages.dtype))
-    v_pages = kv.v_pages.at[layer, page, off, :, :].set(
-        v.astype(kv.v_pages.dtype))
-    return dataclasses.replace(kv, k_pages=k_pages, v_pages=v_pages)
 
 
 # ---------------------------------------------------------------------------

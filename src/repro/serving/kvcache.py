@@ -53,7 +53,7 @@ from repro.kernels.paged_attention import paged_decode_attention
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKV:
-    k_pages: jax.Array       # (L, NP, page, Hkv, hd)
+    k_pages: jax.Array       # (L, NP, Hkv, page, hd): head-major pages
     v_pages: jax.Array
     page_table: jax.Array    # (B, MAXP) int32
     lengths: jax.Array       # (B,) int32
@@ -105,8 +105,8 @@ def paged_cache_init(cfg: ModelConfig, batch_slots: int, max_len: int,
             first_chunk_ratio=1.0)
         alloc = shard_heap(local, D)      # span = pages per device
     return PagedKV(
-        k_pages=jnp.zeros((L, n_pages, page_size, cfg.num_kv_heads, hd), cdt),
-        v_pages=jnp.zeros((L, n_pages, page_size, cfg.num_kv_heads, hd), cdt),
+        k_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
+        v_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
         page_table=jnp.zeros((batch_slots, maxp), jnp.int32),
         lengths=jnp.zeros((batch_slots,), jnp.int32),
         alloc=alloc,
@@ -141,22 +141,22 @@ def ensure_pages(kv: PagedKV, active: jax.Array) -> PagedKV:
     return dataclasses.replace(kv, alloc=alloc, page_table=page_table)
 
 
-def write_token_kv(kv: PagedKV, layer: int, k: jax.Array, v: jax.Array,
+def write_token_kv(kv: PagedKV, layer, k: jax.Array, v: jax.Array,
                    active: jax.Array) -> PagedKV:
-    """Write one token's K/V (B, Hkv, hd) for ``layer`` at each active slot's
-    current position."""
+    """Write one token's K/V (B, Hkv, hd) for ``layer`` (an int or a traced
+    index, e.g. inside a scan over layers) at each active slot's current
+    position."""
     B = kv.lengths.shape[0]
     pos = kv.lengths
     pidx = jnp.minimum(pos // kv.page_size, kv.page_table.shape[1] - 1)
     page = kv.page_table[jnp.arange(B), pidx]
     off = pos % kv.page_size
-    # inactive slots park their write on page 0 slot 0? no: scatter-drop via
-    # an out-of-range page id
+    # inactive slots write to an out-of-range page id: the scatter drops it
     NP = kv.k_pages.shape[1]
     page = jnp.where(active, page, NP)
-    k_pages = kv.k_pages.at[layer, page, off, :, :].set(
+    k_pages = kv.k_pages.at[layer, page, :, off, :].set(
         k.astype(kv.k_pages.dtype))
-    v_pages = kv.v_pages.at[layer, page, off, :, :].set(
+    v_pages = kv.v_pages.at[layer, page, :, off, :].set(
         v.astype(kv.v_pages.dtype))
     return dataclasses.replace(kv, k_pages=k_pages, v_pages=v_pages)
 

@@ -183,7 +183,6 @@ def test_walker_exempts_cond_confined_callback():
 
 def test_walker_flags_callback_in_mesh():
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.core.jax_compat import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("d",))
 
     def region(x):
@@ -191,8 +190,8 @@ def test_walker_flags_callback_in_mesh():
         return x + r
 
     def prog(x):
-        return shard_map(region, mesh=mesh, in_specs=(P("d"),),
-                         out_specs=P("d"))(x)
+        return jax.shard_map(region, mesh=mesh, in_specs=(P("d"),),
+                             out_specs=P("d"))(x)
 
     report = analyze_jaxpr(prog, jnp.zeros((1,), jnp.int32))
     assert "CALLBACK_IN_MESH" in report.codes

@@ -19,10 +19,11 @@ from repro.kernels.rglru_scan.ref import linear_scan_reference
 
 
 def _qkv(key, B, Sq, Sk, Hq, Hkv, D, dtype):
+    """Head-major q (B, Hq, Sq, D) and k/v (B, Hkv, Sk, D)."""
     ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, Sq, Hq, D), dtype)
-    k = jax.random.normal(ks[1], (B, Sk, Hkv, D), dtype)
-    v = jax.random.normal(ks[2], (B, Sk, Hkv, D), dtype)
+    q = jax.random.normal(ks[0], (B, Hq, Sq, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, Sk, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, Sk, D), dtype)
     return q, k, v
 
 
@@ -72,8 +73,8 @@ def test_chunked_ref_vs_dense_ref(rng, kw):
 def test_decode_attention_vs_ref(rng, B, T, Hq, Hkv, D, dtype):
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    k = jax.random.normal(ks[1], (B, T, Hkv, D), dtype)
-    v = jax.random.normal(ks[2], (B, T, Hkv, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, T, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, T, D), dtype)
     lengths = jnp.asarray([T, max(T // 3, 1), 7][:B], jnp.int32)
     ref = decode_attention_reference(q, k, v, lengths)
     out = decode_attention_pallas(q, k, v, lengths, blk_t=32, interpret=True)
@@ -86,8 +87,8 @@ def test_decode_attention_window(rng):
     ks = jax.random.split(rng, 3)
     B, T, Hq, Hkv, D = 2, 128, 4, 2, 16
     q = jax.random.normal(ks[0], (B, Hq, D))
-    k = jax.random.normal(ks[1], (B, T, Hkv, D))
-    v = jax.random.normal(ks[2], (B, T, Hkv, D))
+    k = jax.random.normal(ks[1], (B, Hkv, T, D))
+    v = jax.random.normal(ks[2], (B, Hkv, T, D))
     lengths = jnp.asarray([100, 33], jnp.int32)
     ref = decode_attention_reference(q, k, v, lengths, window=24)
     out = decode_attention_pallas(q, k, v, lengths, window=24, blk_t=32,
@@ -100,8 +101,8 @@ def test_paged_attention_vs_ref(rng):
     Hq = Hkv * G
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (B, Hq, D))
-    k_pages = jax.random.normal(ks[1], (NP, page, Hkv, D))
-    v_pages = jax.random.normal(ks[2], (NP, page, Hkv, D))
+    k_pages = jax.random.normal(ks[1], (NP, Hkv, page, D))
+    v_pages = jax.random.normal(ks[2], (NP, Hkv, page, D))
     page_table = jax.random.permutation(ks[3], NP)[:B * maxp].reshape(B, maxp)
     lengths = jnp.asarray([96, 17, 64], jnp.int32)
     ref = paged_decode_attention_reference(q, k_pages, v_pages, page_table,
@@ -205,3 +206,31 @@ def test_linear_scan_vs_sequential(rng):
         hs = np.asarray(a[:, t]) * hs + np.asarray(b[:, t])
         np.testing.assert_allclose(h[:, t], hs, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(hl, hs, atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Scan ops under a mesh: Mosaic kernels cannot be partitioned by GSPMD, so
+# the ops run them per shard (shard_map over logical axes)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("op", ["ssd_scan", "linear_scan"])
+def test_scan_op_per_shard_under_mesh(rng, monkeypatch, op):
+    from repro.distributed.sharding import ShardingCtx
+    from repro.kernels.rglru_scan import linear_scan
+    from repro.kernels.ssd_scan import ssd_scan
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "interpret")
+    if op == "ssd_scan":
+        args = _ssd_inputs(rng, 2, 32, 4, 8, 16)
+        fn, ref = (lambda *a: ssd_scan(*a, chunk=8),
+                   lambda *a: ssd_scan_reference(*a, chunk=8))
+    else:
+        ks = jax.random.split(rng, 2)
+        args = (jax.nn.sigmoid(jax.random.normal(ks[0], (2, 64, 16))),
+                jax.random.normal(ks[1], (2, 64, 16)))
+        fn, ref = linear_scan, linear_scan_reference
+    with ShardingCtx(make_mesh((1, 1), ("data", "model"))):
+        out = jax.jit(fn)(*args)
+    for got, want in zip(out, ref(*args)):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)

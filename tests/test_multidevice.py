@@ -19,6 +19,8 @@ def run_child(code: str, devices: int = 8, timeout: int = 560) -> str:
     # the child probe for accelerators stalls for minutes on hosts that
     # carry a (here unusable) TPU runtime
     env["JAX_PLATFORMS"] = "cpu"
+    # children build meshes with the repository's Auto-axes helper
+    code = "from repro.launch.mesh import make_mesh\n" + code
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -33,7 +35,7 @@ from repro.configs import CONFIGS
 from repro.distributed.sharding import ShardingCtx
 from repro.models.moe import moe_apply, moe_init, moe_reference
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(CONFIGS["phi3.5-moe-42b-a6.6b"].reduced(),
                           num_experts=8, experts_per_token=2,
                           capacity_factor=8.0)      # no drops
@@ -60,7 +62,7 @@ from repro.configs import CONFIGS
 from repro.distributed.sharding import ShardingCtx
 from repro.models.moe import moe_apply, moe_init, moe_reference
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(CONFIGS["phi3.5-moe-42b-a6.6b"].reduced(),
                           num_experts=8, experts_per_token=2,
                           capacity_factor=8.0)
@@ -87,7 +89,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.expand import (barrier, expand, parallel_for, serial_for,
                                team_id, num_teams, ws_range)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def region():
     tid = team_id()
@@ -121,7 +123,7 @@ import jax
 import repro.launch.dryrun as dr
 from repro.configs import get_config, get_shape
 import dataclasses
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(get_config("llama3.2-3b").reduced(),
                           head_pad_multiple=4)
 shape = dataclasses.replace(get_shape("train_4k"), seq_len=64, global_batch=8)
@@ -138,21 +140,20 @@ def test_hierarchical_psum_multipod():
     out = run_child(r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.jax_compat import shard_map
 from repro.distributed.collectives import hierarchical_psum
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 def f(x):
     return hierarchical_psum(x, intra_axis="data", inter_axis="pod")
 
-g = shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-              out_specs=P(("pod", "data")), check_vma=False)
+g = jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                  out_specs=P(("pod", "data")), check_vma=False)
 x = jnp.arange(8.0)
 out = np.asarray(jax.jit(g)(x))
 # psum over (pod,data) of per-shard values, replicated back per shard:
 # shards hold [0,1],[2,3],[4,5],[6,7] pairs; model axis replicates
-expect = np.asarray(jax.jit(shard_map(
+expect = np.asarray(jax.jit(jax.shard_map(
     lambda x: jax.lax.psum(x, ("pod", "data")), mesh=mesh,
     in_specs=P(("pod", "data")), out_specs=P(("pod", "data")),
     check_vma=False))(x))

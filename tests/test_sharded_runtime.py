@@ -29,6 +29,7 @@ from repro.core.allocator import (
 from repro.core.rpc import (
     READ, REGISTRY, ArenaRef, RpcQueue, ShardedRpcQueue, flush_stats,
     reset_rpc_stats, rpc_call)
+from repro.launch.mesh import make_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -43,6 +44,8 @@ def run_child(code: str, devices: int = 4, timeout: int = 560) -> str:
     # the child probe for accelerators stalls for minutes on hosts that
     # carry a (here unusable) TPU runtime
     env["JAX_PLATFORMS"] = "cpu"
+    # children build meshes with the repository's Auto-axes helper
+    code = "from repro.launch.mesh import make_mesh\n" + code
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -364,7 +367,7 @@ def test_place_sharded_state_single_device():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import (device_axis_spec,
                                             place_sharded_state)
-    mesh = jax.make_mesh((1,), ("dev",))
+    mesh = make_mesh((1,), ("dev",))
     assert device_axis_spec(mesh) == P(("dev",))
     q = ShardedRpcQueue.create(1, 8, width=2)
     q2 = place_sharded_state(q, mesh)
@@ -474,7 +477,7 @@ from repro.core.device_main import device_run
 from repro.core.expand import team_id
 from repro.core.rpc import REGISTRY
 
-mesh = jax.make_mesh((2,), ("dev",))
+mesh = make_mesh((2,), ("dev",))
 REGISTRY.register("mesh.sq", lambda x: np.int32(x) * np.int32(x))
 
 def step(i, s, lq):
@@ -568,7 +571,7 @@ from repro.core.expand import (expand, set_team_heap, set_team_queue,
                                team_heap, team_id, team_ptr, team_queue)
 from repro.core.libc import LogRing, drain_log_lines
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 
 def region():
     st = team_heap()
@@ -610,7 +613,7 @@ from repro.core.device_main import HostHook, device_run
 from repro.core.expand import team_id
 from repro.core.rpc import rpc_stats, reset_rpc_stats
 
-mesh = jax.make_mesh((4,), ("dev",))
+mesh = make_mesh((4,), ("dev",))
 recs = []
 hook = HostHook(every=3,
                 extract=lambda i, s: s[0] + team_id().astype(jnp.float32),
@@ -637,7 +640,7 @@ import jax, jax.numpy as jnp
 from repro.core.device_main import HostHook, device_run
 from repro.core.expand import team_id
 
-mesh = jax.make_mesh((2,), ("dev",))
+mesh = make_mesh((2,), ("dev",))
 recs = []
 hook = HostHook(every=2,
                 extract=lambda i, s: s + team_id().astype(jnp.float32),
@@ -659,7 +662,7 @@ def test_parallel_for_ragged_over_mesh():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.expand import parallel_for, serial_for
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 arr = jnp.arange(64.0)
 body = lambda i, a: a[i] * 3.0 + i
 for n in (30, 7, 64, 61):

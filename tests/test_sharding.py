@@ -9,11 +9,12 @@ from repro.distributed.sharding import (
     LOGICAL_RULES, ShardingCtx, logical_sharding, logical_spec,
     param_sharding_tree, with_logical_constraint, zero1_spec)
 from repro.launch.hlocost import analyze
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_logical_spec_basic(mesh11):
@@ -69,7 +70,7 @@ def test_rules_have_no_duplicate_mesh_axis_per_param():
     from repro.configs import CONFIGS
     from repro.models import build_model
     from repro.models.common import split_params
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for name in ("qwen2.5-14b", "qwen3-moe-235b-a22b", "mamba2-130m",
                  "recurrentgemma-9b", "seamless-m4t-large-v2"):
         cfg = CONFIGS[name].reduced()
