@@ -22,7 +22,7 @@ import sys
 import traceback
 
 from benchmarks import (allocator_bench, amgmk_pagerank_bench, hypterm_bench,
-                        interleaved_bench, roofline, rpc_bench, rsbench_bench,
+                        interleaved_bench, rpc_bench, rsbench_bench,
                         spec_bench, xsbench_bench)
 from benchmarks.common import write_artifact
 from repro.launch.compile_cache import use_persistent_cache
@@ -36,7 +36,6 @@ SUITES = {
     "fig9b_hypterm": hypterm_bench.run,
     "fig9c_amgmk_pagerank": amgmk_pagerank_bench.run,
     "fig10_spec": spec_bench.run,
-    "roofline": roofline.run,
 }
 
 

@@ -375,8 +375,14 @@ def parallel_for(body: Callable, n: int, *arrays,
 
     Work is block-distributed over all mesh devices (teams) and vectorized
     within each block (threads) — ``omp distribute parallel for``.  Without a
-    mesh it still vectorizes (one team, many threads).
+    mesh it still vectorizes (one team, many threads).  The region runs
+    under the ``parallel_for`` named scope (op metadata only).
     """
+    with jax.named_scope("parallel_for"):
+        return _parallel_for(body, n, arrays, mesh)
+
+
+def _parallel_for(body, n, arrays, mesh):
     if mesh is None or mesh.size == 1 or n == 0:
         return jax.vmap(lambda i: body(i, *arrays))(jnp.arange(n))
 

@@ -1182,6 +1182,18 @@ def flush_stats() -> Dict[str, int]:
                 "retries": REGISTRY.retries}
 
 
+def _host_contacts() -> Dict[str, int]:
+    """The transport's counters that the runtime recorder publishes: queue
+    flushes and immediate (landing-pad) RPC calls."""
+    with REGISTRY.lock:
+        return {"flushes": REGISTRY.flushes,
+                "immediate_calls": sum(p["calls"]
+                                       for p in REGISTRY.pad_stats.values())}
+
+
+events.publish("rpc", _host_contacts)
+
+
 def reset_rpc_stats():
     with REGISTRY.lock:
         for s in REGISTRY.stats.values():
@@ -1438,11 +1450,18 @@ def _find_obj(state, ptr):
 # Batched transport: on-device RPC queue, drained by ONE ordered callback
 # ---------------------------------------------------------------------------
 
-def _replay_shard(callee, nargs, imask, pmask, ivals, fvals, plens, pbuf,
-                  rwant, n, overrides, names, hosts, per_name_calls,
-                  per_name_bytes, reply=None, base=0, idem=None,
-                  retry=None, timeout=None, occ=None, carry=None,
-                  abandoned=None) -> Tuple[int, int, int, int]:
+def _replay_shard(*args, **kwargs) -> Tuple[int, int, int, int]:
+    """:func:`_replay_records` inside the ``rpc.drain`` runtime span: every
+    drain variant replays through here, on whichever host thread drains."""
+    with events.span("rpc.drain"):
+        return _replay_records(*args, **kwargs)
+
+
+def _replay_records(callee, nargs, imask, pmask, ivals, fvals, plens, pbuf,
+                    rwant, n, overrides, names, hosts, per_name_calls,
+                    per_name_bytes, reply=None, base=0, idem=None,
+                    retry=None, timeout=None, occ=None, carry=None,
+                    abandoned=None) -> Tuple[int, int, int, int]:
     """Replay one queue shard's records in enqueue order; returns ``(number
     of records overwritten before this flush could drain them, number of
     replies dropped because the reply arena was full, records whose callee

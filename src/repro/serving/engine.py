@@ -29,6 +29,7 @@ from repro.models.mlp import mlp_apply
 from repro.models.moe import moe_apply
 from repro.models.model_zoo import Model
 from repro.models.transformer import _slice_layer
+from repro.core import events
 from repro.core import rpc as rpc_mod
 from repro.core.rpc import REGISTRY, RpcQueue
 from repro.serving import kvcache
@@ -183,11 +184,11 @@ class ServingEngine:
         self.queue: List[Tuple[int, List[int], int]] = []
         self.finished: Dict[int, List[int]] = {}
         self._next_id = 0
-        self._step = jax.jit(
-            lambda values, axes_h, kv, tokens, active: paged_decode_step(
-                merge_params(values, axes_h.tree), kv, tokens, active,
-                self.cfg),
-            static_argnums=(1,))
+        def serve_step(values, axes_h, kv, tokens, active):
+            return paged_decode_step(merge_params(values, axes_h.tree), kv,
+                                     tokens, active, self.cfg)
+
+        self._step = jax.jit(serve_step, static_argnums=(1,))
         self._values, self._axes = split_params(params)
         self._axes_h = _Hashable(self._axes)
         self._geom = {"batch_slots": int(batch_slots),
@@ -203,65 +204,84 @@ class ServingEngine:
         return rid
 
     def step(self) -> None:
-        """One engine tick: refill slots, one batched decode step, harvest."""
-        for s in self.slots:
-            if s.request_id < 0 and self.queue:
-                rid, prompt, max_new = self.queue.pop(0)
-                s.request_id, s.prompt, s.fed, s.out, s.max_new = \
-                    rid, prompt, 0, [], max_new
+        """One engine tick: refill slots, one batched decode step, harvest.
 
-        tokens, active = [], []
-        for s in self.slots:
-            if s.request_id < 0:
-                tokens.append(0)
-                active.append(False)
-            elif s.fed < len(s.prompt):
-                tokens.append(s.prompt[s.fed])
-                active.append(True)
-            else:
-                tokens.append(s.out[-1] if s.out else s.prompt[-1])
-                active.append(True)
+        The tick's phases are runtime spans (``repro.core.events``), in
+        order: ``engine.admit``, ``engine.inputs``, ``engine.dispatch``,
+        ``engine.wait`` (the first device read, which blocks until the
+        step is done), ``engine.harvest`` and ``engine.retire``."""
+        events.count("engine.ticks")
+        with events.span("engine.admit"):
+            for s in self.slots:
+                if s.request_id < 0 and self.queue:
+                    rid, prompt, max_new = self.queue.pop(0)
+                    s.request_id, s.prompt, s.fed, s.out, s.max_new = \
+                        rid, prompt, 0, [], max_new
 
-        tok = jnp.asarray(tokens, jnp.int32)
-        act = jnp.asarray(active)
-        logits, self.kv = self._step(self._values, self._axes_h, self.kv,
-                                     tok, act)
-        nxt = jnp.argmax(logits, axis=-1)
-
-        done_slots = []
-        done_rids = []
-        for i, s in enumerate(self.slots):
-            if s.request_id < 0:
-                continue
-            if s.fed < len(s.prompt):
-                s.fed += 1
-                if s.fed < len(s.prompt):
+        with events.span("engine.inputs"):
+            tokens, active = [], []
+            first = None            # the first slot that reads a token
+            for i, s in enumerate(self.slots):
+                if s.request_id < 0:
+                    tokens.append(0)
+                    active.append(False)
                     continue
-            if s.fed >= len(s.prompt):
-                t = int(nxt[i])
-                s.out.append(t)
-                done = len(s.out) >= s.max_new or \
-                    (self.eos_id is not None and t == self.eos_id)
-                if done:
-                    self.finished[s.request_id] = s.out
-                    done_slots.append(i)
-                    done_rids.append(s.request_id)
-                    self.slots[i] = _Slot()
-        if done_slots:
-            if self.spill_q is not None:
-                # page-spill: every retiring slot's page ids ride the
-                # payload arena; ONE flush delivers the whole tick and its
-                # replies ack every spill (sink return, or page count).
-                # _deliver_spills retries failed records and degrades to
-                # recompute-on-readmit instead of wedging the tick loop.
-                self._deliver_spills(
-                    [(int(rid), self.kv.lengths[i],
-                      kvcache.live_pages(self.kv, i))
-                     for i, rid in zip(done_slots, done_rids)])
-            # every retired request this tick releases in ONE bulk reset
-            mask = jnp.zeros((len(self.slots),), bool).at[
-                jnp.asarray(done_slots, jnp.int32)].set(True)
-            self.kv = kvcache.release_slots(self.kv, mask)
+                if s.fed < len(s.prompt):
+                    tokens.append(s.prompt[s.fed])
+                else:
+                    tokens.append(s.out[-1] if s.out else s.prompt[-1])
+                active.append(True)
+                if first is None and s.fed + 1 >= len(s.prompt):
+                    first = i
+            tok = jnp.asarray(tokens, jnp.int32)
+            act = jnp.asarray(active)
+
+        with events.span("engine.dispatch"):
+            logits, self.kv = self._step(self._values, self._axes_h,
+                                         self.kv, tok, act)
+            nxt = jnp.argmax(logits, axis=-1)
+
+        with events.span("engine.wait"):
+            head = None if first is None else _read_token(nxt, first)
+
+        with events.span("engine.harvest"):
+            done_slots = []
+            done_rids = []
+            for i, s in enumerate(self.slots):
+                if s.request_id < 0:
+                    continue
+                if s.fed < len(s.prompt):
+                    s.fed += 1
+                    if s.fed < len(s.prompt):
+                        continue
+                if s.fed >= len(s.prompt):
+                    t = head if i == first else _read_token(nxt, i)
+                    s.out.append(t)
+                    done = len(s.out) >= s.max_new or \
+                        (self.eos_id is not None and t == self.eos_id)
+                    if done:
+                        self.finished[s.request_id] = s.out
+                        done_slots.append(i)
+                        done_rids.append(s.request_id)
+                        self.slots[i] = _Slot()
+
+        with events.span("engine.retire"):
+            if done_slots:
+                if self.spill_q is not None:
+                    # page-spill: every retiring slot's page ids ride the
+                    # payload arena; ONE flush delivers the whole tick and
+                    # its replies ack every spill (sink return, or page
+                    # count).  _deliver_spills retries failed records and
+                    # degrades to recompute-on-readmit instead of wedging
+                    # the tick loop.
+                    self._deliver_spills(
+                        [(int(rid), self.kv.lengths[i],
+                          kvcache.live_pages(self.kv, i))
+                         for i, rid in zip(done_slots, done_rids)])
+                # every retired request this tick releases in ONE bulk reset
+                mask = jnp.zeros((len(self.slots),), bool).at[
+                    jnp.asarray(done_slots, jnp.int32)].set(True)
+                self.kv = kvcache.release_slots(self.kv, mask)
 
     def _deliver_spills(self, records) -> None:
         """Deliver ``(rid, n_tokens, pages)`` spill records with retry
@@ -380,14 +400,14 @@ class ServingEngine:
         os.makedirs(directory, exist_ok=True)
         axes_tree, cfg = self._axes, self.cfg
 
-        def _serve(values, kv, tokens, active):
+        def serve_step(values, kv, tokens, active):
             return paged_decode_step(merge_params(values, axes_tree),
                                      kv, tokens, active, cfg)
 
         def _spec(x):
             return jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x))
 
-        exported = jax_export.export(jax.jit(_serve))(
+        exported = jax_export.export(jax.jit(serve_step))(
             jax.tree.map(_spec, self._values), jax.tree.map(_spec, self.kv),
             jax.ShapeDtypeStruct((self.B,), jnp.int32),
             jax.ShapeDtypeStruct((self.B,), jnp.bool_))
@@ -471,6 +491,12 @@ class ServingEngine:
                       "page_size": page_size, "eos_id": self.eos_id}
         self._step_source = "artifact"
         return self
+
+
+def _read_token(nxt: jax.Array, i: int) -> int:
+    """Slot ``i``'s next token, read to the host: one device read."""
+    events.count("engine.host_reads")
+    return int(nxt[i])
 
 
 class _Hashable:

@@ -35,10 +35,15 @@ with a ``spill_sink``, every retiring request ships its page-id list
 (:func:`live_pages`) to the host as ONE batched payload RPC — the ids ride
 the RPC queue's on-device arena and the whole tick's retirements drain in
 one ordered callback, instead of a per-page (or per-request) round-trip.
+
+The page-heap operations (``ensure_pages``, ``write_token_kv``,
+``advance``, ``release_slots``) run under the ``kvcache`` named scope, so
+a profile gives their device time to the page heap.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
@@ -74,6 +79,15 @@ jax.export.register_pytree_node_serialization(
     PagedKV, serialized_name="repro.serving.kvcache.PagedKV",
     serialize_auxdata=lambda page_size: str(int(page_size)).encode("ascii"),
     deserialize_auxdata=lambda b: int(b.decode("ascii")))
+
+
+def _scoped(fn):
+    """Run ``fn`` under the ``kvcache`` named scope (op metadata only)."""
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with jax.named_scope("kvcache"):
+            return fn(*args, **kwargs)
+    return wrapper
 
 
 def _mesh_devices(mesh) -> int:
@@ -113,6 +127,7 @@ def paged_cache_init(cfg: ModelConfig, batch_slots: int, max_len: int,
         page_size=page_size)
 
 
+@_scoped
 def ensure_pages(kv: PagedKV, active: jax.Array) -> PagedKV:
     """Allocate a page for every active slot whose next token crosses a page
     boundary.  One balanced-allocator grid call: chunks are per-slot, so the
@@ -141,6 +156,7 @@ def ensure_pages(kv: PagedKV, active: jax.Array) -> PagedKV:
     return dataclasses.replace(kv, alloc=alloc, page_table=page_table)
 
 
+@_scoped
 def write_token_kv(kv: PagedKV, layer, k: jax.Array, v: jax.Array,
                    active: jax.Array) -> PagedKV:
     """Write one token's K/V (B, Hkv, hd) for ``layer`` (an int or a traced
@@ -170,6 +186,7 @@ def paged_attend(kv: PagedKV, layer: int, q: jax.Array,
         kv.lengths + 1, window=window)
 
 
+@_scoped
 def advance(kv: PagedKV, active: jax.Array) -> PagedKV:
     return dataclasses.replace(
         kv, lengths=kv.lengths + active.astype(jnp.int32))
@@ -199,6 +216,7 @@ def release_slot(kv: PagedKV, slot: int) -> PagedKV:
         lengths=kv.lengths.at[slot].set(0))
 
 
+@_scoped
 def release_slots(kv: PagedKV, mask: jax.Array) -> PagedKV:
     """Bulk request completion: release every slot where ``mask`` (B,) is
     true in ONE vectorized allocator reset — the free-side counterpart of
