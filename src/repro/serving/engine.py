@@ -208,8 +208,10 @@ class ServingEngine:
 
         The tick's phases are runtime spans (``repro.core.events``), in
         order: ``engine.admit``, ``engine.inputs``, ``engine.dispatch``,
-        ``engine.wait`` (the first device read, which blocks until the
-        step is done), ``engine.harvest`` and ``engine.retire``."""
+        ``engine.wait`` (the tick's one device-to-host transfer of every
+        slot's next token, which blocks until the step is done; skipped
+        when no slot reads a token), ``engine.harvest`` (host bookkeeping
+        only) and ``engine.retire``."""
         events.count("engine.ticks")
         with events.span("engine.admit"):
             for s in self.slots:
@@ -220,7 +222,7 @@ class ServingEngine:
 
         with events.span("engine.inputs"):
             tokens, active = [], []
-            first = None            # the first slot that reads a token
+            reads = False           # some slot reads a token this tick
             for i, s in enumerate(self.slots):
                 if s.request_id < 0:
                     tokens.append(0)
@@ -231,8 +233,7 @@ class ServingEngine:
                 else:
                     tokens.append(s.out[-1] if s.out else s.prompt[-1])
                 active.append(True)
-                if first is None and s.fed + 1 >= len(s.prompt):
-                    first = i
+                reads = reads or s.fed + 1 >= len(s.prompt)
             tok = jnp.asarray(tokens, jnp.int32)
             act = jnp.asarray(active)
 
@@ -242,7 +243,7 @@ class ServingEngine:
             nxt = jnp.argmax(logits, axis=-1)
 
         with events.span("engine.wait"):
-            head = None if first is None else _read_token(nxt, first)
+            host = _read_tokens(nxt) if reads else None
 
         with events.span("engine.harvest"):
             done_slots = []
@@ -255,7 +256,8 @@ class ServingEngine:
                     if s.fed < len(s.prompt):
                         continue
                 if s.fed >= len(s.prompt):
-                    t = head if i == first else _read_token(nxt, i)
+                    t = host[i]
+                    events.count("engine.host_reads")
                     s.out.append(t)
                     done = len(s.out) >= s.max_new or \
                         (self.eos_id is not None and t == self.eos_id)
@@ -493,10 +495,10 @@ class ServingEngine:
         return self
 
 
-def _read_token(nxt: jax.Array, i: int) -> int:
-    """Slot ``i``'s next token, read to the host: one device read."""
-    events.count("engine.host_reads")
-    return int(nxt[i])
+def _read_tokens(nxt: jax.Array) -> List[int]:
+    """Every slot's next token, read to the host in one transfer."""
+    events.count("engine.host_transfers")
+    return np.asarray(nxt).tolist()
 
 
 class _Hashable:

@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from repro.configs import CONFIGS
+from repro.core import events
 from repro.models import build_model
+from repro.serving import engine as engine_mod
 from repro.serving import kvcache
 from repro.serving.engine import ServingEngine
 
@@ -188,6 +190,67 @@ def test_engine_mixed_lengths_continuous_batching(dense_model):
     res = eng.run_until_drained()
     for rid, ref in zip(rids, refs):
         assert res[rid] == ref, rid
+
+
+#: mixed prompt and output lengths; the first four prompts (>= 3 tokens)
+#: fill every slot, so the first ticks have no slot reading a token
+_MIXED = [([4, 8, 15], 2), ([16, 23, 42, 3], 4), ([7, 1, 9], 3),
+          ([2, 6, 5, 3, 1], 2), ([11], 5), ([12, 14], 1), ([19, 5, 2], 3)]
+
+
+def test_engine_one_host_transfer_per_decoding_tick(dense_model):
+    """Each tick moves every slot's next token to the host in one transfer
+    when some slot decodes, and in none when every slot is still feeding
+    its prompt; ``engine.host_reads`` still counts one per decoding slot."""
+    cfg, model, params = dense_model
+    eng = ServingEngine(model, params, batch_slots=4, max_len=64,
+                        page_size=8)
+    for prompt, n in _MIXED:
+        eng.submit(prompt, max_new=n)
+    seen = set()
+    while eng.queue or any(s.request_id >= 0 for s in eng.slots):
+        free = sum(1 for s in eng.slots if s.request_id < 0)
+        decoding = sum(1 for s in eng.slots if s.request_id >= 0
+                       and s.fed + 1 >= len(s.prompt))
+        decoding += sum(1 for _, p, _ in eng.queue[:free] if len(p) <= 1)
+        with events.recording() as rec:
+            eng.step()
+        assert rec.deltas.get("engine.host_transfers", 0) == \
+            (1 if decoding else 0)
+        assert rec.deltas.get("engine.host_reads", 0) == decoding
+        seen.add(bool(decoding))
+    assert seen == {False, True} and len(eng.finished) == len(_MIXED)
+
+
+class _PerSlotReads:
+    """The per-slot harvest: each decoding slot's token read to the host
+    alone, as an eager slice and its own transfer."""
+
+    def __init__(self, nxt):
+        self.nxt = nxt
+
+    def __getitem__(self, i):
+        return int(self.nxt[i])
+
+
+@pytest.mark.parametrize("mesh", [None, 2])
+def test_engine_batched_harvest_matches_per_slot_reads(dense_model,
+                                                       monkeypatch, mesh):
+    """The one-transfer harvest serves, token for token, what per-slot
+    reads serve on the same submissions, and every token is a Python int."""
+    cfg, model, params = dense_model
+    results = []
+    for read in (None, _PerSlotReads):
+        if read is not None:
+            monkeypatch.setattr(engine_mod, "_read_tokens", read)
+        eng = ServingEngine(model, params, batch_slots=4, max_len=64,
+                            page_size=8, mesh=mesh)
+        rids = [eng.submit(p, max_new=n) for p, n in _MIXED]
+        res = eng.run_until_drained()
+        results.append([res[r] for r in rids])
+    assert results[0] == results[1]
+    assert [len(out) for out in results[0]] == [n for _, n in _MIXED]
+    assert all(type(t) is int for out in results[0] for t in out)
 
 
 def test_engine_sharded_page_heap_matches(dense_model):
