@@ -15,6 +15,7 @@ from repro.configs import (
     phi3_5_moe,
     seamless_m4t_v2,
     recurrentgemma_9b,
+    deepseek_v2,
 )
 
 _MODULES = (
@@ -28,6 +29,7 @@ _MODULES = (
     phi3_5_moe,
     seamless_m4t_v2,
     recurrentgemma_9b,
+    deepseek_v2,
 )
 
 CONFIGS = {m.CONFIG.name: m.CONFIG for m in _MODULES}
@@ -44,6 +46,7 @@ ALIASES = {
     "phi3.5-moe-42b-a6.6b": "phi3.5-moe-42b-a6.6b",
     "seamless-m4t-large-v2": "seamless-m4t-large-v2",
     "recurrentgemma-9b": "recurrentgemma-9b",
+    "deepseek-v2": "deepseek-v2",
 }
 
 

@@ -45,6 +45,40 @@ class ModelConfig:
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # DeepSeekMoE: routing groups (experts are scored, the best
+    # ``topk_group`` of ``n_group`` groups kept, then the top
+    # ``experts_per_token`` of those), the routed weights' scale and
+    # normalisation, always-on shared experts, the expert width when it is
+    # not ``d_ff``, and the leading layers that keep a dense MLP
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    n_shared_experts: int = 0
+    moe_d_ff: int = 0                       # default: d_ff
+    first_k_dense: int = 0
+    # the share of the routed experts this device holds: experts
+    # ``first_expert .. first_expert + experts_held - 1`` (0: all of them).
+    # The router still scores all ``num_experts``; the layer returns the part
+    # of the output that its own experts give (expert parallelism, one shard)
+    experts_held: int = 0
+    first_expert: int = 0
+
+    # -- latent attention (MLA, DeepSeek-V2) ------------------------------------
+    # queries and keys/values pass through low-rank latents; the decode cache
+    # holds one ``kv_lora_rank + qk_rope_head_dim`` row per token and layer
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 0: plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # -- ssm (mamba2 / SSD) --------------------------------------------------
     ssm_state: int = 0
@@ -84,9 +118,8 @@ class ModelConfig:
     # this so head-TP shards evenly on the 16-way model axis (40 q heads on
     # 16 devices would otherwise replicate attention entirely)
     head_pad_multiple: int = 16
-    # remat policy: "full" (recompute everything; the 16 GB/chip
-    # HBM budget at 4k x 256 batch demands it — see EXPERIMENTS.md
-    # §Perf iteration 0), "dots", "none"
+    # remat policy: "full" (recompute everything; the 16 GB/chip HBM
+    # budget at 4k x 256 batch demands it), "dots", "none"
     remat: str = "full"
 
     # -------------------------------------------------------------------------
@@ -124,6 +157,24 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one MLA cache row: the normed KV latent and the shared
+        rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -143,6 +194,14 @@ class ModelConfig:
 
     # -- parameter counting ----------------------------------------------------
     def _attn_params(self) -> int:
+        if self.is_mla:
+            H, qk = self.num_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
+            p = self.d_model * self.q_lora_rank + self.q_lora_rank     # q_a, norm
+            p += self.q_lora_rank * H * qk                              # q_b
+            p += self.d_model * self.latent_width + self.kv_lora_rank  # kv_a, norm
+            p += self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim)
+            p += H * self.v_head_dim * self.d_model                     # out proj
+            return p
         hd = self.resolved_head_dim
         p = self.d_model * (self.q_dim + 2 * self.kv_dim)          # qkv
         p += self.q_dim * self.d_model                              # out proj
@@ -199,12 +258,16 @@ class ModelConfig:
             return emb + head + enc + dec
         # dense / moe / vlm share a decoder-only skeleton
         attn = self._attn_params()
-        if self.is_moe:
-            mlp = self.num_experts * self._mlp_params(self.d_ff) + self.d_model * self.num_experts
-        else:
-            mlp = self._mlp_params(self.d_ff)
-        per_layer = attn + mlp + 2 * d
-        return emb + head + L * per_layer
+        dense = attn + self._mlp_params(self.d_ff) + 2 * d
+        if not self.is_moe:
+            return emb + head + L * dense
+        moe = attn + self.held_experts * self._mlp_params(self.expert_d_ff) \
+            + self._shared_params() + d * self.num_experts + 2 * d
+        k = self.first_k_dense
+        return emb + head + k * dense + (L - k) * moe
+
+    def _shared_params(self) -> int:
+        return self._mlp_params(self.n_shared_experts * self.expert_d_ff)
 
     def active_params(self) -> int:
         """Parameters touched per token (MoE: only routed experts)."""
@@ -214,8 +277,11 @@ class ModelConfig:
         emb = self.vocab_size * d
         head = 0 if self.tie_embeddings else self.vocab_size * d
         attn = self._attn_params()
-        mlp = self.experts_per_token * self._mlp_params(self.d_ff) + d * self.num_experts
-        return emb + head + L * (attn + mlp + 2 * d)
+        dense = attn + self._mlp_params(self.d_ff) + 2 * d
+        mlp = self.experts_per_token * self._mlp_params(self.expert_d_ff) \
+            + self._shared_params() + d * self.num_experts
+        k = self.first_k_dense
+        return emb + head + k * dense + (L - k) * (attn + mlp + 2 * d)
 
     # -- smoke-test reduction ----------------------------------------------------
     def reduced(self) -> "ModelConfig":
@@ -237,6 +303,17 @@ class ModelConfig:
         )
         if self.is_moe:
             updates.update(num_experts=4, experts_per_token=2)
+        if self.n_group > 1:
+            # 8 experts in 4 groups, the best 2 groups, top-2 of them
+            updates.update(num_experts=8, n_group=4, topk_group=2,
+                           experts_held=0, first_expert=0)
+        if self.moe_d_ff:
+            updates.update(moe_d_ff=32)
+        if self.first_k_dense:
+            updates.update(num_layers=3, first_k_dense=1)
+        if self.is_mla:
+            updates.update(q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+                           qk_rope_head_dim=8, v_head_dim=16, num_kv_heads=heads)
         if self.family == "ssm":
             updates.update(ssm_state=16, ssm_head_dim=16, ssd_chunk=8)
         if self.family == "hybrid":

@@ -12,6 +12,13 @@ Two execution paths, both driven by the same parameters:
 
 * **Reference** — without a mesh (single-team semantics): a dropless dense
   evaluation over all experts; the oracle used by the tests.
+
+DeepSeekMoE layers (group-limited routing, shared experts, or a device that
+holds a share of the experts) take :func:`moe_share_apply`: the router
+scores all ``num_experts``, and the layer computes, for every token, the
+part of the output that the experts it holds give, plus the shared
+experts.  On one device that needs no exchange; expert parallelism sums
+the shares of the devices.
 """
 from __future__ import annotations
 
@@ -27,21 +34,27 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import current_mesh, with_logical_constraint as wlc
 from repro.models.common import Param, normal
+from repro.models.mlp import mlp_apply, mlp_init
 
 
 def moe_init(key, cfg: ModelConfig) -> dict:
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    d, f, E = cfg.d_model, cfg.expert_d_ff, cfg.held_experts
     ks = jax.random.split(key, 4)
     pd = jnp.dtype(cfg.param_dtype)
-    return {
+    p = {
         # router stays replicated: it is tiny and its output drives a
         # data-dependent dispatch (sharding it would all-gather logits anyway)
-        "router": normal(ks[0], (d, E), (None, None), jnp.dtype("float32"), scale=0.02),
+        "router": normal(ks[0], (d, cfg.num_experts), (None, None),
+                         jnp.dtype("float32"), scale=0.02),
         "wi_gate": normal(ks[1], (E, d, f), ("experts", "fsdp", "expert_ffn"), pd),
         "wi_up": normal(ks[2], (E, d, f), ("experts", "fsdp", "expert_ffn"), pd),
         "wo": normal(ks[3], (E, f, d), ("experts", "expert_ffn", "fsdp"), pd,
                      scale=f ** -0.5),
     }
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_init(jax.random.fold_in(key, 4), cfg,
+                               d_ff=cfg.n_shared_experts * f)
+    return p
 
 
 def _route(x_flat: jax.Array, router_w: jax.Array, k: int):
@@ -166,9 +179,77 @@ def _moe_local_replicated(x_row, router_w, wg, wu, wo, *, E: int, K: int,
     return y.astype(x_row.dtype), aux
 
 
+def group_limited_route(x_flat: jax.Array, router_w: jax.Array,
+                        cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek's ``group_limited_greedy`` routing over all ``num_experts``:
+    softmax scores; each group of ``num_experts / n_group`` scored by its
+    best expert; the ``topk_group`` best groups kept; the top
+    ``experts_per_token`` experts of those chosen.  Returns (weights (T, E)
+    fp32, zero off the chosen experts and scaled by
+    ``routed_scaling_factor``, renormalised first when ``norm_topk_prob``;
+    scores (T, E) fp32)."""
+    logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
+                        router_w.astype(jnp.float32))
+    scores = jax.nn.softmax(logits, axis=-1)
+    T, E = scores.shape
+    G = cfg.n_group
+    masked = scores
+    if G > 1:
+        best = jnp.max(scores.reshape(T, G, E // G), axis=-1)
+        _, top_groups = lax.top_k(best, cfg.topk_group)
+        keep = jnp.zeros((T, G), bool).at[
+            jnp.arange(T)[:, None], top_groups].set(True)
+        masked = jnp.where(jnp.repeat(keep, E // G, axis=1), scores, 0.0)
+    topv, topi = lax.top_k(masked, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    topv = topv * cfg.routed_scaling_factor
+    w = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], topi].set(topv)
+    return w, scores
+
+
+def moe_share_apply(p: dict, x: jax.Array, cfg: ModelConfig
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (y, aux).  ``y`` is what the held experts
+    ``first_expert .. first_expert + held_experts - 1`` give each token
+    under the routing over all experts, plus the shared experts.  Every
+    held expert is evaluated on every token and weighted by its routing
+    weight (zero where it was not chosen), so no token is dropped: at a
+    decode batch the expert weights' read bounds the layer either way."""
+    B, S, d = x.shape
+    T, E = B * S, cfg.num_experts
+    dt = x.dtype
+    x_flat = x.reshape(T, d)
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            w, scores = group_limited_route(x_flat, p["router"].value, cfg)
+            first = cfg.first_expert
+            w_held = w[:, first:first + cfg.held_experts]
+        with jax.named_scope("experts"):
+            g = jnp.einsum("td,edf->tef", x_flat, p["wi_gate"].value.astype(dt))
+            u = jnp.einsum("td,edf->tef", x_flat, p["wi_up"].value.astype(dt))
+            h = (jax.nn.silu(g) * u).astype(jnp.float32) * w_held[..., None]
+            y = jnp.einsum("tef,efd->td", h.astype(dt), p["wo"].value.astype(dt))
+        y = y.reshape(B, S, d)
+        if cfg.n_shared_experts:
+            with jax.named_scope("shared"):
+                y = y + mlp_apply(p["shared"], x)
+        counts = jnp.sum(w > 0, axis=0).astype(jnp.float32)
+        f_e = counts / jnp.maximum(jnp.sum(counts), 1.0)
+        aux = E * jnp.sum(f_e * jnp.mean(scores, axis=0))
+    return wlc(y, "batch", "seq", "embed"), aux
+
+
+def uses_expert_share(cfg: ModelConfig) -> bool:
+    """Whether the layer routes DeepSeek's way (:func:`moe_share_apply`)."""
+    return cfg.n_group > 1 or cfg.n_shared_experts > 0 or cfg.experts_held > 0
+
+
 def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig
               ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (y (B,S,d), aux_loss scalar)."""
+    if uses_expert_share(cfg):
+        return moe_share_apply(p, x, cfg)
     vals = {k: v.value for k, v in p.items()}
     B, S, d = x.shape
     T = B * S

@@ -17,11 +17,11 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import with_logical_constraint as wlc
 from repro.models import common
+from repro.models import mla
 from repro.models.attention import attn_init, attn_apply, attn_decode
 from repro.models.common import (
-    Param, merge_params, rmsnorm, rmsnorm_init, split_params, stack_params)
+    merge_params, rmsnorm, rmsnorm_init, split_params, stack_params)
 from repro.models.mlp import mlp_init, mlp_apply
-from repro.models.common import Param
 from repro.models.moe import moe_init, moe_apply
 from repro.models.rglru import (
     rglru_apply, rglru_cache_axes, rglru_decode, rglru_init, rglru_init_cache)
@@ -33,15 +33,15 @@ from repro.models.ssd import (
 # Layer init
 # ---------------------------------------------------------------------------
 
-def _attn_layer_init(key, cfg: ModelConfig) -> dict:
+def _attn_layer_init(key, cfg: ModelConfig, moe: bool = False) -> dict:
     k1, k2 = jax.random.split(key)
     pd = jnp.dtype(cfg.param_dtype)
     layer = {
         "ln1": rmsnorm_init(cfg.d_model, pd),
-        "attn": attn_init(k1, cfg),
+        "attn": mla.mla_init(k1, cfg) if cfg.is_mla else attn_init(k1, cfg),
         "ln2": rmsnorm_init(cfg.d_model, pd),
     }
-    if cfg.is_moe:
+    if moe:
         layer["moe"] = moe_init(k2, cfg)
     else:
         layer["mlp"] = mlp_init(k2, cfg)
@@ -95,10 +95,22 @@ def lm_init(key, cfg: ModelConfig) -> dict:
         params["rec_layers"] = stack_params(rec)
         params["attn_layers"] = stack_params(att)
     else:
-        layers = [_attn_layer_init(keys[i + 2], cfg) for i in range(cfg.num_layers)]
-        params["layers"] = stack_params(layers)
+        k = cfg.first_k_dense
+        layers = [_attn_layer_init(keys[i + 2], cfg, cfg.is_moe and i >= k)
+                  for i in range(cfg.num_layers)]
+        if k:
+            params["dense_layers"] = stack_params(layers[:k])
+        params["layers"] = stack_params(layers[k:])
     return params
 
+
+def layer_stacks(cfg: ModelConfig) -> Tuple[Tuple[str, int, int, bool], ...]:
+    """The decoder's stacks of layers in order, each (params key, first
+    layer, layer count, MoE): the ``first_k_dense`` leading layers keep a
+    dense MLP, the rest are ``cfg``'s kind."""
+    k = cfg.first_k_dense
+    dense = (("dense_layers", 0, k, False),) if k else ()
+    return dense + (("layers", k, cfg.num_layers - k, cfg.is_moe),)
 
 
 
@@ -106,7 +118,7 @@ def _lm_head(params, cfg: ModelConfig) -> jax.Array:
     """LM head weights (d, V).  Tied embeddings live in gather-friendly
     layout (V@fsdp, d@model); the head matmul wants (d, V@model) — reshard
     ONCE here (77 MB for a 50k vocab) instead of letting GSPMD improvise
-    full-logit materializations (see EXPERIMENTS.md §Dry-run)."""
+    full-logit materializations."""
     if cfg.tie_embeddings:
         head = params["embed"].value.T
         return wlc(head, None, "vocab")
@@ -153,6 +165,8 @@ def _angles_for(cfg: ModelConfig, batch, B: int, S: int) -> Optional[jax.Array]:
     positions = batch.get("positions")
     if positions is None:
         positions = common.default_positions(B, S, cfg)
+    if cfg.is_mla:
+        return mla.rope_angles(positions, cfg)
     return common.rope_angles(positions, cfg.resolved_head_dim, cfg.rope_theta,
                               cfg.mrope_sections)
 
@@ -180,25 +194,27 @@ def lm_forward(params, batch, cfg: ModelConfig, *, causal: bool = True
         x, aux_total = _hybrid_forward(params, x, angles, cfg, causal)
 
     else:
-        stacked_vals, stacked_axes = split_params(params["layers"])
+        attend = mla.mla_apply if cfg.is_mla else attn_apply
+        for name, _, _, moe in layer_stacks(cfg):
+            stacked_vals, stacked_axes = split_params(params[name])
 
-        def body(carry, layer_vals):
-            x, aux = carry
-            layer = _slice_layer(stacked_axes, layer_vals)
-            h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
-            x = x + attn_apply(layer["attn"], h, cfg, angles=angles,
+            def body(carry, layer_vals, stacked_axes=stacked_axes, moe=moe):
+                x, aux = carry
+                layer = _slice_layer(stacked_axes, layer_vals)
+                h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
+                x = x + attend(layer["attn"], h, cfg, angles=angles,
                                causal=causal)
-            h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
-            if cfg.is_moe:
-                y, a = moe_apply(layer["moe"], h, cfg)
-                aux = aux + a
-            else:
-                y = mlp_apply(layer["mlp"], h)
-            x = wlc(x + y, "batch", "seq", "embed")
-            return (x, aux), ()
+                h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
+                if moe:
+                    y, a = moe_apply(layer["moe"], h, cfg)
+                    aux = aux + a
+                else:
+                    y = mlp_apply(layer["mlp"], h)
+                x = wlc(x + y, "batch", "seq", "embed")
+                return (x, aux), ()
 
-        (x, aux_total), _ = lax.scan(_remat(body, cfg), (x, aux_total),
-                                     stacked_vals)
+            (x, aux_total), _ = lax.scan(_remat(body, cfg), (x, aux_total),
+                                         stacked_vals)
 
     x = rmsnorm(x, params["ln_f"].value, cfg.norm_eps)
     logits = common.lm_logits(x, _lm_head(params, cfg), cfg)
@@ -353,6 +369,13 @@ def lm_init_cache(cfg: ModelConfig, batch: int, max_len: int):
             "lengths": ("batch",),
         }
         return vals, axes
+    if cfg.is_mla:
+        vals = {"latent": jnp.zeros((cfg.num_layers, batch, max_len,
+                                     cfg.latent_width), cdt),
+                "lengths": jnp.zeros((batch,), jnp.int32)}
+        axes = {"latent": ("stack", "batch", "seq_kv", None),
+                "lengths": ("batch",)}
+        return vals, axes
     vals = {
         "k": jnp.zeros((cfg.num_layers, batch, cfg.num_kv_heads, max_len, hd), cdt),
         "v": jnp.zeros((cfg.num_layers, batch, cfg.num_kv_heads, max_len, hd), cdt),
@@ -439,28 +462,42 @@ def lm_prefill(params, batch, cfg: ModelConfig, max_len: int
         }
 
     else:
-        stacked_vals, stacked_axes = split_params(params["layers"])
+        filled = []
+        for name, _, _, moe in layer_stacks(cfg):
+            stacked_vals, stacked_axes = split_params(params[name])
 
-        def body(x, layer_vals):
-            layer = _slice_layer(stacked_axes, layer_vals)
-            h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
-            a, (k, v) = attn_apply(layer["attn"], h, cfg, angles=angles,
-                                   return_kv=True)
-            x = x + a
-            h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
-            if cfg.is_moe:
-                y, _ = moe_apply(layer["moe"], h, cfg)
-            else:
-                y = mlp_apply(layer["mlp"], h)
-            return wlc(x + y, "batch", "seq", "embed"), (k, v)
+            def body(x, layer_vals, stacked_axes=stacked_axes, moe=moe):
+                layer = _slice_layer(stacked_axes, layer_vals)
+                h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
+                if cfg.is_mla:
+                    a, kv = mla.mla_apply(layer["attn"], h, cfg, angles=angles,
+                                          return_latent=True)
+                else:
+                    a, kv = attn_apply(layer["attn"], h, cfg, angles=angles,
+                                       return_kv=True)
+                x = x + a
+                h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
+                if moe:
+                    y, _ = moe_apply(layer["moe"], h, cfg)
+                else:
+                    y = mlp_apply(layer["mlp"], h)
+                return wlc(x + y, "batch", "seq", "embed"), kv
 
-        x, (ks, vs) = lax.scan(body, x, stacked_vals)
+            x, kv = lax.scan(body, x, stacked_vals)
+            filled.append(kv)
         pad = max_len - S
-        kc = jnp.pad(ks, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
-                     ).astype(cache["k"].dtype)
-        vc = jnp.pad(vs, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
-                     ).astype(cache["v"].dtype)
-        cache = {"k": kc, "v": vc, "lengths": lengths}
+        if cfg.is_mla:
+            rows = jnp.concatenate(filled, axis=0)
+            cache = {"latent": jnp.pad(rows, ((0, 0), (0, 0), (0, pad), (0, 0))
+                                       ).astype(cache["latent"].dtype),
+                     "lengths": lengths}
+        else:
+            (ks, vs), = filled
+            kc = jnp.pad(ks, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
+                         ).astype(cache["k"].dtype)
+            vc = jnp.pad(vs, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
+                         ).astype(cache["v"].dtype)
+            cache = {"k": kc, "v": vc, "lengths": lengths}
 
     x = rmsnorm(x, params["ln_f"].value, cfg.norm_eps)
     logits = common.lm_logits(x[:, -1:], _lm_head(params, cfg), cfg)[:, 0]
@@ -484,6 +521,8 @@ def lm_decode_step(params, cache, tokens, cfg: ModelConfig,
 
     if cfg.family == "ssm":
         angles = None
+    elif cfg.is_mla:
+        angles = mla.rope_angles(lengths[:, None], cfg)
     else:
         pos = lengths[:, None]
         if cfg.mrope_sections:
@@ -506,6 +545,9 @@ def lm_decode_step(params, cache, tokens, cfg: ModelConfig,
 
     elif cfg.family == "hybrid":
         x, new_cache = _hybrid_decode(params, cache, x, angles, cfg)
+
+    elif cfg.is_mla:
+        x, new_cache = _latent_decode(params, cache, x, angles, cfg)
 
     else:
         stacked_vals, stacked_axes = split_params(params["layers"])
@@ -532,6 +574,32 @@ def lm_decode_step(params, cache, tokens, cfg: ModelConfig,
     x = rmsnorm(x, params["ln_f"].value, cfg.norm_eps)
     logits = common.lm_logits(x, _lm_head(params, cfg), cfg)[:, 0]
     return logits, new_cache
+
+
+def _latent_decode(params, cache, x, angles, cfg: ModelConfig):
+    """MLA decode over the layer stacks against the contiguous latent
+    cache (L, B, T, W)."""
+    lengths = cache["lengths"]
+    new = []
+    for name, first, n, moe in layer_stacks(cfg):
+        stacked_vals, stacked_axes = split_params(params[name])
+
+        def body(x, scanned, stacked_axes=stacked_axes, moe=moe):
+            layer_vals, c = scanned
+            layer = _slice_layer(stacked_axes, layer_vals)
+            h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
+            a, c = mla.mla_decode(layer["attn"], h, cfg, cache=c,
+                                  lengths=lengths, angles=angles)
+            x = x + a
+            h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
+            y = moe_apply(layer["moe"], h, cfg)[0] if moe \
+                else mlp_apply(layer["mlp"], h)
+            return x + y, c
+
+        x, c = lax.scan(body, x, (stacked_vals,
+                                  cache["latent"][first:first + n]))
+        new.append(c)
+    return x, {"latent": jnp.concatenate(new, axis=0), "lengths": lengths + 1}
 
 
 def _hybrid_decode(params, cache, x, angles, cfg: ModelConfig):

@@ -5,9 +5,10 @@ lock-step (one jitted ``serve_step`` for the whole batch); finished slots are
 released in O(1) (balanced-allocator watermark reclaim) and refilled from the
 request queue without disturbing in-flight neighbors.
 
-Attention-family models use the paged KV cache; SSM/hybrid models have O(1)
-recurrent state, so they use their native state caches through the same slot
-machinery (paging is pointless for constant-size state — noted in DESIGN.md).
+Attention-family models use the paged KV cache (MLA models its latent
+kind: one row per token and layer); SSM/hybrid models have O(1) recurrent
+state, so they use their native state caches through ``Model.decode_step``
+(paging is pointless for constant-size state).
 """
 from __future__ import annotations
 
@@ -22,13 +23,13 @@ import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.models import common
+from repro.models import common, mla
 from repro.models.attention import _mask_heads, _project_qkv
 from repro.models.common import merge_params, rmsnorm, split_params
 from repro.models.mlp import mlp_apply
 from repro.models.moe import moe_apply
 from repro.models.model_zoo import Model
-from repro.models.transformer import _slice_layer
+from repro.models.transformer import _slice_layer, layer_stacks
 from repro.core import events
 from repro.core import rpc as rpc_mod
 from repro.core.rpc import REGISTRY, RpcQueue
@@ -55,9 +56,21 @@ def paged_decode_step(params, kv: PagedKV, tokens: jax.Array,
                       active: jax.Array, cfg: ModelConfig
                       ) -> Tuple[jax.Array, PagedKV]:
     """tokens: (B,) -> (logits (B, V), kv')."""
-    B = tokens.shape[0]
     kv = kvcache.ensure_pages(kv, active)
     x = common.embed_tokens(params["embed"].value, tokens[:, None], cfg)
+    layers = _latent_layers if cfg.is_mla else _kv_layers
+    x, kv = layers(params, kv, x, active, cfg)
+    kv = kvcache.advance(kv, active)
+    x = rmsnorm(x, params["ln_f"].value, cfg.norm_eps)
+    head = params["embed"].value.T if cfg.tie_embeddings \
+        else params["lm_head"].value
+    logits = common.lm_logits(x, head, cfg)[:, 0]
+    return logits, kv
+
+
+def _kv_layers(params, kv: PagedKV, x, active, cfg: ModelConfig):
+    """The decoder layers over the K/V pages (GQA attention)."""
+    B = x.shape[0]
     pos = kv.lengths[:, None]
     if cfg.mrope_sections:
         pos = jnp.broadcast_to(pos[None], (len(cfg.mrope_sections), B, 1))
@@ -87,12 +100,38 @@ def paged_decode_step(params, kv: PagedKV, tokens: jax.Array,
 
     (x, kv), _ = lax.scan(body, (x, kv),
                           (stacked_vals, jnp.arange(L, dtype=jnp.int32)))
-    kv = kvcache.advance(kv, active)
-    x = rmsnorm(x, params["ln_f"].value, cfg.norm_eps)
-    head = params["embed"].value.T if cfg.tie_embeddings \
-        else params["lm_head"].value
-    logits = common.lm_logits(x, head, cfg)[:, 0]
-    return logits, kv
+    return x, kv
+
+
+def _latent_layers(params, kv: PagedKV, x, active, cfg: ModelConfig):
+    """The decoder layers over the latent pages (MLA, absorbed): the
+    leading dense layers, then the MoE stack, each a scan."""
+    angles = mla.rope_angles(kv.lengths[:, None], cfg)
+    scale = mla.softmax_scale(cfg)
+    for name, first, n, moe in layer_stacks(cfg):
+        stacked_vals, stacked_axes = split_params(params[name])
+
+        def body(carry, scanned, stacked_axes=stacked_axes, moe=moe):
+            x, kv = carry
+            layer_vals, li = scanned
+            layer = _slice_layer(stacked_axes, layer_vals)
+            h = rmsnorm(x, layer["ln1"].value, cfg.norm_eps)
+            with jax.named_scope("mla"):
+                q, row = mla.absorbed_query(layer["attn"], h, cfg, angles)
+                kv = kvcache.write_token_latent(kv, li, row, active)
+                o = kvcache.latent_attend(kv, li, q, rank=cfg.kv_lora_rank,
+                                          scale=scale)
+                a = mla.absorbed_output(layer["attn"], o, x.dtype)
+            x = x + a[:, None]
+            h = rmsnorm(x, layer["ln2"].value, cfg.norm_eps)
+            y = moe_apply(layer["moe"], h, cfg)[0] if moe \
+                else mlp_apply(layer["mlp"], h)
+            return (x + y, kv), ()
+
+        (x, kv), _ = lax.scan(
+            body, (x, kv),
+            (stacked_vals, jnp.arange(first, first + n, dtype=jnp.int32)))
+    return x, kv
 
 
 # ---------------------------------------------------------------------------

@@ -36,9 +36,17 @@ with a ``spill_sink``, every retiring request ships its page-id list
 the RPC queue's on-device arena and the whole tick's retirements drain in
 one ordered callback, instead of a per-page (or per-request) round-trip.
 
+**Latent pages** (MLA models, ``cfg.is_mla``): a page holds one row per
+token and layer, ``[rmsnorm(c_kv) | rope(k_pe)]`` (``cfg.latent_width``
+wide), and no K or V pages: every head scores the whole row and reads its
+first ``kv_lora_rank`` entries as the value (``latent_attend``, the
+``latent_attention`` Pallas kernel on TPU).  Rows are stored zero-padded to
+a multiple of 128 lanes, which is what a TPU tile occupies anyway.
+
 The page-heap operations (``ensure_pages``, ``write_token_kv``,
-``advance``, ``release_slots``) run under the ``kvcache`` named scope, so
-a profile gives their device time to the page heap.
+``write_token_latent``, ``advance``, ``release_slots``) run under the
+``kvcache`` named scope, so a profile gives their device time to the page
+heap.
 """
 from __future__ import annotations
 
@@ -52,27 +60,37 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.allocator import (BalancedAllocator, BalancedState,
                                   ShardedAllocator, ShardedHeap, shard_heap)
+from repro.kernels.latent_attention import latent_paged_decode_attention
 from repro.kernels.paged_attention import paged_decode_attention
+
+#: lanes of a TPU tile: latent rows are stored padded to a multiple of it
+LANES = 128
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKV:
-    k_pages: jax.Array       # (L, NP, Hkv, page, hd): head-major pages
-    v_pages: jax.Array
+    k_pages: Optional[jax.Array]   # (L, NP, Hkv, page, hd): head-major pages
+    v_pages: Optional[jax.Array]   # (None for the latent kind)
     page_table: jax.Array    # (B, MAXP) int32
     lengths: jax.Array       # (B,) int32
     alloc: Union[BalancedState, ShardedHeap]  # page-slot allocator
     #                          (arena = page-id space; sharded under a mesh)
     page_size: int
+    latent_pages: Optional[jax.Array] = None   # (L, NP, page, W): MLA rows
 
     def tree_flatten(self):
         return ((self.k_pages, self.v_pages, self.page_table, self.lengths,
-                 self.alloc), self.page_size)
+                 self.alloc, self.latent_pages), self.page_size)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, aux)
+        k, v, pt, lengths, alloc, latent = leaves
+        return cls(k, v, pt, lengths, alloc, aux, latent)
+
+    @property
+    def is_latent(self) -> bool:
+        return self.latent_pages is not None
 
 
 jax.export.register_pytree_node_serialization(
@@ -97,6 +115,12 @@ def _mesh_devices(mesh) -> int:
     return int(mesh) if isinstance(mesh, int) else int(mesh.size)
 
 
+def latent_row_width(cfg: ModelConfig) -> int:
+    """Stored width of an MLA cache row: ``cfg.latent_width`` rounded up to
+    whole lanes."""
+    return -(-cfg.latent_width // LANES) * LANES
+
+
 def paged_cache_init(cfg: ModelConfig, batch_slots: int, max_len: int,
                      *, page_size: int = 64,
                      n_pages: Optional[int] = None, mesh=None) -> PagedKV:
@@ -118,13 +142,18 @@ def paged_cache_init(cfg: ModelConfig, batch_slots: int, max_len: int,
             n_pages // D, batch_slots // D, 1, cap=maxp,
             first_chunk_ratio=1.0)
         alloc = shard_heap(local, D)      # span = pages per device
+    common = dict(page_table=jnp.zeros((batch_slots, maxp), jnp.int32),
+                  lengths=jnp.zeros((batch_slots,), jnp.int32),
+                  alloc=alloc, page_size=page_size)
+    if cfg.is_mla:
+        return PagedKV(
+            k_pages=None, v_pages=None, **common,
+            latent_pages=jnp.zeros(
+                (L, n_pages, page_size, latent_row_width(cfg)), cdt))
     return PagedKV(
         k_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
         v_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
-        page_table=jnp.zeros((batch_slots, maxp), jnp.int32),
-        lengths=jnp.zeros((batch_slots,), jnp.int32),
-        alloc=alloc,
-        page_size=page_size)
+        **common)
 
 
 @_scoped
@@ -156,25 +185,54 @@ def ensure_pages(kv: PagedKV, active: jax.Array) -> PagedKV:
     return dataclasses.replace(kv, alloc=alloc, page_table=page_table)
 
 
-@_scoped
-def write_token_kv(kv: PagedKV, layer, k: jax.Array, v: jax.Array,
-                   active: jax.Array) -> PagedKV:
-    """Write one token's K/V (B, Hkv, hd) for ``layer`` (an int or a traced
-    index, e.g. inside a scan over layers) at each active slot's current
-    position."""
+def _token_slots(kv: PagedKV, active: jax.Array, NP: int):
+    """Each slot's (page, offset) for its current position."""
     B = kv.lengths.shape[0]
     pos = kv.lengths
     pidx = jnp.minimum(pos // kv.page_size, kv.page_table.shape[1] - 1)
     page = kv.page_table[jnp.arange(B), pidx]
     off = pos % kv.page_size
     # inactive slots write to an out-of-range page id: the scatter drops it
-    NP = kv.k_pages.shape[1]
-    page = jnp.where(active, page, NP)
+    return jnp.where(active, page, NP), off
+
+
+@_scoped
+def write_token_kv(kv: PagedKV, layer, k: jax.Array, v: jax.Array,
+                   active: jax.Array) -> PagedKV:
+    """Write one token's K/V (B, Hkv, hd) for ``layer`` (an int or a traced
+    index, e.g. inside a scan over layers) at each active slot's current
+    position."""
+    page, off = _token_slots(kv, active, kv.k_pages.shape[1])
     k_pages = kv.k_pages.at[layer, page, :, off, :].set(
         k.astype(kv.k_pages.dtype))
     v_pages = kv.v_pages.at[layer, page, :, off, :].set(
         v.astype(kv.v_pages.dtype))
     return dataclasses.replace(kv, k_pages=k_pages, v_pages=v_pages)
+
+
+@_scoped
+def write_token_latent(kv: PagedKV, layer, row: jax.Array,
+                       active: jax.Array) -> PagedKV:
+    """Write one token's latent row (B, latent_width) for ``layer`` at each
+    active slot's current position (zero-padded to the stored width)."""
+    W = kv.latent_pages.shape[-1]
+    page, off = _token_slots(kv, active, kv.latent_pages.shape[1])
+    row = jnp.pad(row, ((0, 0), (0, W - row.shape[-1])))
+    pages = kv.latent_pages.at[layer, page, off, :].set(
+        row.astype(kv.latent_pages.dtype))
+    return dataclasses.replace(kv, latent_pages=pages)
+
+
+def latent_attend(kv: PagedKV, layer, q: jax.Array, *, rank: int,
+                  scale: float) -> jax.Array:
+    """q: (B, H, latent_width) absorbed queries, one token per slot ->
+    (B, H, rank) attended latents over lengths+1 rows (the current token's
+    row was just written)."""
+    W = kv.latent_pages.shape[-1]
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, W - q.shape[-1])))
+    return latent_paged_decode_attention(
+        q, kv.latent_pages, jnp.asarray(layer, jnp.int32), kv.page_table,
+        kv.lengths + 1, rank=rank, scale=scale)
 
 
 def paged_attend(kv: PagedKV, layer: int, q: jax.Array,

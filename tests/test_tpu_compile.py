@@ -18,6 +18,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.latent_attention.kernel import (
+    latent_paged_decode_attention_pallas)
 from repro.kernels.paged_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.rglru_scan.kernel import linear_scan_pallas
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
@@ -65,6 +67,20 @@ def test_paged_attention_compiles(one_chip):
         _spec((NP, HKV, page, HD), jnp.bfloat16, one_chip),
         _spec((B, maxp), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip))
+
+
+def test_latent_attention_compiles(one_chip):
+    # DeepSeek-V2: 128 heads over 576-wide latent rows stored 640 wide,
+    # 512-wide values; 6 layers of pages, 8 pages a grid step
+    L, B, NP, page, maxp = 6, 4, 64, 16, 16
+    text = latent_paged_decode_attention_pallas.lower(
+        _spec((B, 128, 640), jnp.bfloat16, one_chip),
+        _spec((L, NP, page, 640), jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((B, maxp), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        rank=512, scale=0.1).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_decode_attention_compiles(one_chip):
