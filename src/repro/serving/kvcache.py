@@ -70,8 +70,8 @@ LANES = 128
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKV:
-    k_pages: Optional[jax.Array]   # (L, NP, Hkv, page, hd): head-major pages
-    v_pages: Optional[jax.Array]   # (None for the latent kind)
+    k_pages: Optional[jax.Array]   # (L, NP, page * Hkv, hd): row t * Hkv + h
+    v_pages: Optional[jax.Array]   # is token t of KV head h (None: latent)
     page_table: jax.Array    # (B, MAXP) int32
     lengths: jax.Array       # (B,) int32
     alloc: Union[BalancedState, ShardedHeap]  # page-slot allocator
@@ -150,10 +150,9 @@ def paged_cache_init(cfg: ModelConfig, batch_slots: int, max_len: int,
             k_pages=None, v_pages=None, **common,
             latent_pages=jnp.zeros(
                 (L, n_pages, page_size, latent_row_width(cfg)), cdt))
-    return PagedKV(
-        k_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
-        v_pages=jnp.zeros((L, n_pages, cfg.num_kv_heads, page_size, hd), cdt),
-        **common)
+    rows = page_size * cfg.num_kv_heads
+    return PagedKV(k_pages=jnp.zeros((L, n_pages, rows, hd), cdt),
+                   v_pages=jnp.zeros((L, n_pages, rows, hd), cdt), **common)
 
 
 @_scoped
@@ -201,12 +200,12 @@ def write_token_kv(kv: PagedKV, layer, k: jax.Array, v: jax.Array,
                    active: jax.Array) -> PagedKV:
     """Write one token's K/V (B, Hkv, hd) for ``layer`` (an int or a traced
     index, e.g. inside a scan over layers) at each active slot's current
-    position."""
+    position: rows ``off * Hkv ...`` of its page."""
+    hkv = k.shape[1]
     page, off = _token_slots(kv, active, kv.k_pages.shape[1])
-    k_pages = kv.k_pages.at[layer, page, :, off, :].set(
-        k.astype(kv.k_pages.dtype))
-    v_pages = kv.v_pages.at[layer, page, :, off, :].set(
-        v.astype(kv.v_pages.dtype))
+    page, row = page[:, None], off[:, None] * hkv + jnp.arange(hkv)
+    k_pages = kv.k_pages.at[layer, page, row].set(k.astype(kv.k_pages.dtype))
+    v_pages = kv.v_pages.at[layer, page, row].set(v.astype(kv.v_pages.dtype))
     return dataclasses.replace(kv, k_pages=k_pages, v_pages=v_pages)
 
 
@@ -235,13 +234,14 @@ def latent_attend(kv: PagedKV, layer, q: jax.Array, *, rank: int,
         kv.lengths + 1, rank=rank, scale=scale)
 
 
-def paged_attend(kv: PagedKV, layer: int, q: jax.Array,
+def paged_attend(kv: PagedKV, layer, q: jax.Array,
                  window: Optional[int] = None) -> jax.Array:
     """q: (B, Hq, hd) one token per slot -> (B, Hq, hd).  Attends over
-    lengths+1 entries (the current token was just written)."""
+    lengths+1 entries (the current token was just written) of ``layer``'s
+    pages, read in place from the cache of every layer."""
     return paged_decode_attention(
-        q, kv.k_pages[layer], kv.v_pages[layer], kv.page_table,
-        kv.lengths + 1, window=window)
+        q, kv.k_pages, kv.v_pages, jnp.asarray(layer, jnp.int32),
+        kv.page_table, kv.lengths + 1, page_size=kv.page_size, window=window)
 
 
 @_scoped

@@ -96,20 +96,45 @@ def test_decode_attention_window(rng):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_paged_attention_vs_ref(rng):
-    B, NP, page, Hkv, G, D, maxp = 3, 24, 16, 2, 2, 32, 6
-    Hq = Hkv * G
+@pytest.mark.parametrize("Hkv,G,lengths,window", [
+    (1, 4, (1, 16, 320), None),       # MQA; one token, a page boundary, all
+    (2, 2, (320, 17, 129), None),     # 129: one past a grid step's pages
+    (4, 2, (0, 33, 200), None),       # an inactive slot
+    (8, 5, (319, 1, 128), None),      # Qwen2.5's 40 / 8 heads
+    (2, 3, (320, 150, 5), 40),        # sliding window across a grid step
+], ids=["hkv1", "hkv2", "hkv4-inactive", "hkv8", "hkv2-window"])
+def test_paged_attention_vs_ref(rng, Hkv, G, lengths, window):
+    """Interpret-mode kernel vs the paged reference and vs dense attention
+    over the gathered cache, at a layer other than the first; 20 page slots
+    a slot, so the walk takes three grid steps, the last one partial."""
+    B, NP, page, D, maxp, L, layer = 3, 64, 16, 32, 20, 3, 2
     ks = jax.random.split(rng, 4)
-    q = jax.random.normal(ks[0], (B, Hq, D))
-    k_pages = jax.random.normal(ks[1], (NP, Hkv, page, D))
-    v_pages = jax.random.normal(ks[2], (NP, Hkv, page, D))
+    q = jax.random.normal(ks[0], (B, Hkv * G, D))
+    k_pages = jax.random.normal(ks[1], (L, NP, page * Hkv, D))
+    v_pages = jax.random.normal(ks[2], (L, NP, page * Hkv, D))
     page_table = jax.random.permutation(ks[3], NP)[:B * maxp].reshape(B, maxp)
-    lengths = jnp.asarray([96, 17, 64], jnp.int32)
-    ref = paged_decode_attention_reference(q, k_pages, v_pages, page_table,
-                                           lengths)
-    out = paged_decode_attention_pallas(q, k_pages, v_pages, page_table,
-                                        lengths, interpret=True)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    ref = paged_decode_attention_reference(
+        q, k_pages, v_pages, layer, page_table, lengths, page_size=page,
+        window=window)
+    out = paged_decode_attention_pallas(
+        q, k_pages, v_pages, jnp.int32(layer), page_table, lengths,
+        page_size=page, window=window, interpret=True)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    # row t * Hkv + h of page p is token t of head h: dense attention over
+    # the pages the table names, unpacked one token at a time
+    def dense(pages):
+        g = np.asarray(pages[layer])[np.asarray(page_table)]
+        return np.stack([g[:, :, h::Hkv].reshape(B, maxp * page, D)
+                         for h in range(Hkv)], axis=1)
+
+    want = decode_attention_reference(q, dense(k_pages), dense(v_pages),
+                                      lengths, window=window)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[~live], 0.0)
 
 
 # ---------------------------------------------------------------------------
