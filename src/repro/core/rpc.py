@@ -14,15 +14,13 @@ opaque marshalled buffers) is the paper's.  "Compile time" is trace time: the
 first trace of a call site with a new flattened signature *generates* its
 landing pad, exactly like the LTO pass monomorphizing a variadic callee.
 
-Transport v2 semantics
-======================
+Immediate RPCs
+==============
 
 **Order-preserving marshalling.**  Arguments are flattened in *call-site
 order*: each argument contributes its operands in place, so the host callee
 receives ``fn(args...)`` exactly as written at the call site for any mix of
-value / ``Ref`` / ``ArenaRef`` arguments.  (v1 grouped all value args before
-all ref args, silently permuting any call with a value argument after a
-``Ref``.)
+value / ``Ref`` / ``ArenaRef`` arguments.
 
 **Landing-pad-keyed dispatch.**  ``REGISTRY.pads`` maps ``(callee, flattened
 signature)`` to a pad id; each pad owns ONE cached host wrapper, created at
@@ -43,18 +41,21 @@ compiler may elide, cache, or reorder the call, so it is only sound for pure
 host functions whose result is actually consumed; write-back refs are
 rejected (there is no ordering to make a host-side mutation meaningful).
 
-**Batched transport (v3: variable-width records).**  :class:`RpcQueue` is an
-on-device ring of RPC records plus a flat on-device **payload arena**.  Each
-record is ``(callee id, up to W arguments)``; a *scalar* argument packs into
-an int32 or float32 lane (``imask`` bit j records which lane argument ``j``
-used, so mixed int/float argument order survives the trip), while an *array*
+Batched transport
+=================
+
+**Records and the payload arena.**  :class:`RpcQueue` is an on-device ring
+of RPC records plus a flat on-device **payload arena**.  Each record is
+``(callee id, up to W arguments)``; a *scalar* argument packs into an int32
+or float32 lane (``imask`` bit j records which lane argument ``j`` used, so
+mixed int/float argument order survives the trip), while an *array*
 argument rides the arena: its words are copied into ``pbuf`` at the current
-payload watermark and the record stores a **descriptor** in argument ``j``'s
-lanes — offset in ``ivals[.., j]``, length in ``plens[.., j]``, presence in
-``pmask`` bit j, and dtype tag in ``imask`` bit j (set = int32 words, clear
-= float32 words bitcast into the i32 arena).  One watermark bump reserves
-space for ALL of a record's payloads (the allocator-v2 prefix-sum
-discipline: per-payload offsets are static partial sums of the lengths).
+payload watermark and the record stores a **descriptor** in argument
+``j``'s lanes — offset in ``ivals[.., j]``, length in ``plens[.., j]``,
+presence in ``pmask`` bit j, and dtype tag in ``imask`` bit j (set = int32
+words, clear = float32 words bitcast into the i32 arena).  One watermark
+bump reserves space for ALL of a record's payloads (per-payload offsets are
+static partial sums of the lengths).
 
 ``enqueue`` is a pure array update inside jit — no host contact; ``flush``
 drains records AND arena to the host in ONE ordered ``io_callback``,
@@ -62,9 +63,9 @@ replaying records (payloads reattached from their descriptors) in enqueue
 order (generalizing the buffered-``fprintf`` trick that ``core/libc.py``'s
 ``LogRing`` applies to log records, and the antidote to the paper's Fig. 7
 ~975 µs per-call RPC cost).  The device has already executed past the
-enqueue when the callee runs, so write-back refs are rejected — but since
-v4 record callees CAN return values to the device: see the reply arena
-below.  :func:`rpc_call` exposes the same path as ``rpc_call(name, *args,
+enqueue when the callee runs, so write-back refs are rejected; a callee
+returns values to the device through the reply arena below.
+:func:`rpc_call` exposes the same path as ``rpc_call(name, *args,
 batched=True, queue=q)`` — value args only (scalars or arrays), returning
 the updated queue (plus a ticket with ``returns=``).
 
@@ -80,47 +81,44 @@ arena words are written, no descriptor is stored, the head does not advance
 are counted on device and surfaced separately (``arena_drops`` /
 ``last_arena_drops`` in ``flush_stats()``).
 
-**Reply arena (v4): device-visible results for queued RPCs.**  The paper's
-RPC is bidirectional — the host executes the call and hands the result back
-to the device — but fire-and-forget records cannot return values.  A queue
-created with ``reply_capacity > 0`` closes the loop: ``flush`` becomes a
-two-phase epoch.  Phase one is unchanged (ONE ordered ``io_callback``
-drains records + payload arena and replays the callees); phase two is the
-callback's RETURN value — a flat i32 **reply buffer** (integer replies
-stored raw, float replies bitcast, mirroring the request arena) plus a
-per-slot ``(offset, length)`` reply table, scattered back into the queue's
-device-resident reply state.  Each enqueue is keyed by a **ticket** — its
-enqueue order within the epoch (``head`` at enqueue time; ``-1`` for
-records dropped at enqueue) — and ``enqueue_ticketed(...,
+**Reply arena: device-visible results for queued RPCs.**  The paper's RPC
+is bidirectional — the host executes the call and hands the result back to
+the device.  A queue created with ``reply_capacity > 0`` makes ``flush`` a
+two-phase epoch: the one ordered ``io_callback`` drains records + payload
+arena and replays the callees, and its RETURN value is a flat i32 **reply
+buffer** (integer replies stored raw, float replies bitcast, mirroring the
+request arena) plus a per-slot ``(offset, length)`` reply table, scattered
+back into the queue's device-resident reply state.  Each enqueue is keyed
+by a **ticket** — its enqueue order within the epoch (``head`` at enqueue
+time; ``-1`` for records dropped at enqueue) — and ``enqueue_ticketed(...,
 returns=ShapeDtypeStruct)`` declares the expected reply (count + dtype
 stored in the record's ``rwant`` lane: ``+words`` integer, ``-words``
 float).  After flush, device code reads ``queue.result(ticket, shape,
 dtype)``: an O(1) dynamic slice of the reply buffer.  Tickets are GLOBAL
-sequence numbers (they never reset), and each flush stamps the reply
-table with its epoch's base — so a ticket only resolves against the flush
-that serviced it: a stale ticket held across a later flush, or a dropped
+sequence numbers (they never reset), and each flush stamps the reply table
+with its epoch's base — so a ticket only resolves against the flush that
+serviced it: a stale ticket held across a later flush, or a dropped
 ticket, reads zeros, never another record's bytes.  The one remaining
 alias is ring overwrite WITHIN an epoch: an overwritten record's ticket
-reads the surviving record in its slot (when the reply length matches) —
-the same caveat ring overwrite always had.
+reads the surviving record in its slot (when the reply length matches).
 A record whose declared reply does not fit the remaining reply arena is
 dropped WHOLE at drain — its callee is NOT run (an effectful callee must
 not consume input or reserve memory when its result can never reach the
 requester), the reader sees zeros, and the drop is counted in
 ``flush_stats()['reply_drops']`` — the reply-side mirror of the request
-arena's atomic enqueue drop.
-``rpc_call(name, *args, batched=True, queue=q, returns=ShapeDtype)``
-exposes the path generically, returning ``(queue, ticket)`` — the
-blocking-at-flush result path that makes input-style libc (``fread``,
-``fgets``) and device-usable remote-malloc pointers possible.
+arena's atomic enqueue drop.  ``rpc_call(name, *args, batched=True,
+queue=q, returns=ShapeDtype)`` exposes the path generically, returning
+``(queue, ticket)`` — the blocking-at-flush result path that makes
+input-style libc (``fread``, ``fgets``) and device-usable remote-malloc
+pointers possible.
 
-**Fault-tolerant host boundary (v5).**  The drain ISOLATES every callee:
-an exception or per-callee wall-clock ``timeout`` overrun fails only that
+**Fault-tolerant host boundary.**  The drain ISOLATES every callee: an
+exception or per-callee wall-clock ``timeout`` overrun fails only that
 record — traceback captured in :func:`error_log`, counts in
 ``flush_stats()['callee_errors']`` — while the remaining records replay in
 the same deterministic order.  Reply-carrying queues add a per-slot STATUS
-lane: ``result_status(ticket)`` distinguishes OK / CALLEE_RAISED /
-TIMEOUT / DROPPED / REPLY_OVERFLOW / STALE, and ``result_ok`` requires
+lane: ``result_status(ticket)`` distinguishes OK / CALLEE_RAISED / TIMEOUT
+/ DROPPED / REPLY_OVERFLOW / STALE, and ``result_ok`` requires
 ``STATUS_OK``.  ``RpcQueue.create(retry=RetryPolicy(...), timeout=...)``
 adds drain-side retry with exponential backoff, gated by the callee's
 ``register(idempotent=True)`` declaration.  ``queue.pressure()`` exposes
@@ -135,28 +133,27 @@ through one logical queue would serialize the machine on a single ring.
 device (leading device axis on every lane array AND on the payload arena,
 partitioned by ``shard_map``); inside an expanded region each device
 enqueues into its own shard — payload copies included — with zero
-cross-device traffic, and ``flush`` gathers all shards and replays records
-in ``(flush-order, device, slot)`` order on the host — a deterministic
-total order, payloads reattached per shard.  The reply arena stacks the
-same way: one reply buffer + reply table PER DEVICE, filled in that same
-deterministic replay order, so ``q.local(d).result(ticket, ...)`` (or
-``q.result(d, ticket, ...)``) after the flush reads device ``d``'s
-replies regardless of how the drain interleaved the shards.  ``core/libc.py``'s ``LogRing``
-rides it unchanged (a sharded ring is a sharded queue of width-3 records).  Flush of
-a *traced* sharded queue works in single-program (vmapped logical devices)
-form; when the shards live on a real multi-device mesh, flush at the
-program boundary instead (``device_run(mesh=...)`` does) — XLA cannot lower
-a gathered callback inside the same program as the partitioned loop.
+cross-device traffic, and ``flush`` replays records in ``(flush-order,
+device, slot)`` order on the host — a deterministic total order, payloads
+reattached per shard.  The reply arena stacks the same way: one reply
+buffer + reply table PER DEVICE, so ``q.local(d).result(ticket, ...)`` (or
+``q.result(d, ticket, ...)``) after the flush reads device ``d``'s replies
+regardless of how the drain interleaved the shards.  ``core/libc.py``'s
+``LogRing`` rides it unchanged (a sharded ring is a sharded queue of
+width-3 records).  Flush of a *traced* sharded queue works in
+single-program (vmapped logical devices) form; when the shards live on a
+real multi-device mesh, flush at the program boundary instead
+(``device_run(mesh=...)`` does) — XLA cannot lower a gathered callback
+inside the same program as the partitioned loop.
 
-**Async double-buffered transport (v6).**  A queue created with
-``mode="async"`` stops paying the drain on the device clock: ``flush``
-becomes a PING-PONG epoch hand-off.  The callback SUBMITS the just-closed
-epoch's records (a copied snapshot) to a host-side single-thread executor
-owned by the queue's **slot** (allocated at ``create``; per *(slot,
-device)* for sharded queues) and immediately COLLECTS the previous
-epoch's finished drain as its return value — so host-callee time overlaps
-the device compute that runs between flushes instead of serializing with
-it.  Consequences, all visible in the API:
+**Async double-buffered epochs.**  A queue created with ``mode="async"``
+stops paying the drain on the device clock: ``flush`` becomes a PING-PONG
+epoch hand-off.  The callback SUBMITS the just-closed epoch's records (a
+copied snapshot) to a host-side single-thread executor owned by the
+queue's **slot** (allocated at ``create``; one per *(slot, device)*) and
+immediately COLLECTS the previous epoch's finished drain as its return
+value — so host-callee time overlaps the device compute that runs between
+flushes.  Consequences, all visible in the API:
 
 * **Replies land one epoch late.**  The reply window a flush installs is
   the PREVIOUS epoch's; tickets of the epoch just submitted read
@@ -165,16 +162,11 @@ it.  Consequences, all visible in the API:
   the tail" idiom; ``join()`` waits for the background work without
   collecting).  The analyzer flags a raw ``result()`` of a pending ticket
   as ``PENDING_TICKET_READ``.
-* **Per-device independent drains.**  A sharded async flush submits one
-  job per shard to per-``(slot, device)`` executors — no host-side gather
-  barrier, shards drain concurrently.  Determinism is recovered
-  structurally: each shard's executor is FIFO over its epoch sequence
-  (per-shard epoch sequence numbers), so per-shard replay order — and
-  therefore every status and reply — is deterministic; only the
-  cross-shard interleaving of host effects is not.  Fault plans stay
-  seed-deterministic because occurrence indices are RESERVED at submit
-  time in canonical ``(device, slot)`` order (see
-  :mod:`repro.testing.faults`).
+* **Per-device independent drains.**  Each shard's epoch drains on its own
+  executor — no host-side gather barrier.  Each executor is FIFO over its
+  shard's epochs, so per-shard replay order — and therefore every status
+  and reply — is deterministic; only the cross-shard interleaving of host
+  effects is not.
 * **Cross-epoch retry carry.**  ``create(..., carry_budget=N)`` lets a
   failing record (``CALLEE_RAISED``/``TIMEOUT`` after in-drain retries,
   idempotent callees only) be CARRIED host-side into the next epoch's
@@ -185,12 +177,22 @@ it.  Consequences, all visible in the API:
   ``results_host()``; the carried depth returns to the device as the
   ``cdepth`` leaf, which ``pressure()`` folds into the occupancy max —
   a degrading host IS backpressure.
-* **Per-shard drain deadlines.**  ``create(..., shard_deadline=secs)``
-  bounds how long a flush waits for each shard's previous-epoch drain
-  (and, on a SYNC sharded queue, drains shards concurrently with that
-  per-shard budget): a stalled shard's records are stamped
-  ``STATUS_TIMEOUT`` and its siblings complete — partial-epoch
-  completion instead of one hung shard stalling the gather.
+
+**One drain core.**  Every flush — single or sharded, with or without
+replies, sanitized or not, sync or async — goes through one function,
+``_flush``, which hands ``io_callback`` the ONE host drain, ``_drain``,
+bound to the queue's static fields (one cached callable per queue
+geometry, so re-traces hand ``io_callback`` the same object).  The drain
+treats a single queue as one shard and runs each shard's replay under one
+of three schedules: **serial** in device order (sync); **concurrent**
+under a shared wall-clock budget (sync sharded queues created with
+``shard_deadline=secs``: a stalled shard's records read ``STATUS_TIMEOUT``
+while its siblings complete — partial-epoch completion); or **submitted**
+to and collected from the slot's executors (async; ``shard_deadline``
+then bounds each shard's collect).  The concurrent and async schedules
+reserve fault-occurrence indices up front in the serial ``(device,
+slot)`` order (see :mod:`repro.testing.faults`), so fault plans stay
+seed-deterministic whichever thread replays a record.
 
 **CPU async-dispatch deadlock (why ``RpcQueue.create`` warns).**  Under
 ``jax_cpu_enable_async_dispatch=True`` the CPU backend enqueues programs
@@ -228,6 +230,7 @@ Argument categories (paper Fig. 3):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import threading
@@ -237,7 +240,7 @@ import warnings
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from queue import Empty as _QueueEmpty, SimpleQueue as _SimpleQueue
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1350,9 +1353,9 @@ def rpc_call(name: str, *args, result_shape=None, ordered: bool = True,
     flushes.  ``where`` (optional traced bool) makes the enqueue
     conditional.  This is the paper-§3.5 path for array-carrying library
     calls — buffered ``fwrite``, bulk remote mallocs whose size vectors
-    ride the arena — that v2 forced onto a per-call ordered callback.
+    ride the arena — without a per-call ordered callback.
 
-    ``batched=True, returns=jax.ShapeDtypeStruct(...)`` is the v4
+    ``batched=True, returns=jax.ShapeDtypeStruct(...)`` is the
     blocking-at-flush result path: the call returns ``(queue', ticket)``
     instead, and after the queue flushes the host function's return value
     is readable on device as ``queue.result(ticket, returns)`` — the reply
@@ -1452,7 +1455,8 @@ def _find_obj(state, ptr):
 
 def _replay_shard(*args, **kwargs) -> Tuple[int, int, int, int]:
     """:func:`_replay_records` inside the ``rpc.drain`` runtime span: every
-    drain variant replays through here, on whichever host thread drains."""
+    schedule of :func:`_drain` replays each shard through here, on
+    whichever host thread drains it."""
     with events.span("rpc.drain"):
         return _replay_records(*args, **kwargs)
 
@@ -1715,203 +1719,6 @@ def _finish_flush(drops: int, arena_drops: int, per_name_calls,
         REGISTRY.bump(name, None, per_name_bytes[name], 0, calls=calls)
 
 
-def _bind_drain(fn, handlers, retry=None, timeout=None, shard_deadline=None):
-    """Close ``handlers`` and the queue's retry/timeout/deadline policy over
-    a drain callable — or return the stable module-level callable untouched
-    when there is nothing to bind (the jit cache and callback registry key
-    on callable identity, so the default path must always hand
-    ``io_callback`` the same object).  The fault INJECTOR is deliberately
-    not bound: it is looked up at dispatch time, so one traced program runs
-    with and without faults."""
-    if (not handlers and retry is None and timeout is None
-            and shard_deadline is None):
-        return fn
-    bound = dict(handlers) if handlers else None
-
-    if shard_deadline is None:
-        def drain(*flat):
-            return fn(*flat, overrides=bound, retry=retry, timeout=timeout)
-    else:
-        def drain(*flat):
-            return fn(*flat, overrides=bound, retry=retry, timeout=timeout,
-                      shard_deadline=shard_deadline)
-
-    return drain
-
-
-def _drain_queue(callee, nargs, imask, pmask, ivals, fvals, plens, pbuf,
-                 head, phead, adrops, base, overrides=None, retry=None,
-                 timeout=None):
-    """Host side of :meth:`RpcQueue.flush` (reply-less queues): replay
-    queued records in enqueue order, dispatching each to its registered
-    callee (resolved at drain time), unless ``overrides`` maps the callee's
-    name to a handler captured by this particular flush.
-
-    Keeps the v3 operand tuple — no ``rwant`` lane: a reply-less flush
-    never reads it, so shipping it would be a dead (capacity,)-word
-    device-to-host transfer on every fire-and-forget flush.
-
-    A module-level function, so every default flush of every queue hands
-    ``io_callback`` the same stable callable."""
-    # the callback may receive jax Arrays; materialize to numpy ONCE so the
-    # per-record scalar indexing below doesn't pay a device sync each time
-    callee, nargs, imask, pmask, ivals, fvals, plens, pbuf = (
-        np.asarray(x) for x in (callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf))
-    n = int(head)
-    per_name_calls: Dict[str, int] = {}
-    per_name_bytes: Dict[str, int] = {}
-    with REGISTRY.lock:                    # one snapshot, not per record
-        names = dict(REGISTRY.batch_names)
-        hosts = dict(REGISTRY.hosts)
-        idem = dict(REGISTRY.idempotent)
-    drops, _, cerrs, nretries = _replay_shard(
-        callee, nargs, imask, pmask, ivals, fvals, plens, pbuf, None, n,
-        overrides, names, hosts, per_name_calls, per_name_bytes,
-        base=int(base), idem=idem, retry=retry, timeout=timeout)
-    _finish_flush(drops, int(adrops), per_name_calls, per_name_bytes,
-                  callee_errors=cerrs, retries=nretries)
-    return np.int32(n)
-
-
-def _drain_queue_replies(callee, nargs, imask, pmask, ivals, fvals, plens,
-                         pbuf, rwant, head, phead, adrops, base, rc,
-                         overrides=None, retry=None, timeout=None):
-    """Host side of the TWO-PHASE flush (``reply_capacity > 0`` queues):
-    phase one replays records exactly like :func:`_drain_queue`; phase two
-    returns the reply quadruple ``(rbuf, roff, rlen, rstat)`` the device
-    scatters into its reply state — the flat i32 reply buffer, the
-    per-slot offset/length table keyed by ticket slot, and the per-slot
-    STATUS lane ``result_status`` reads.  ``rc`` (the static reply
-    capacity) travels as a scalar operand so this stays ONE stable
-    module-level callable for every reply-carrying queue."""
-    callee, nargs, imask, pmask, ivals, fvals, plens, pbuf, rwant = (
-        np.asarray(x) for x in (callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf, rwant))
-    n = int(head)
-    rc = int(rc)
-    cap = callee.shape[0]
-    rwords = np.zeros((rc,), np.int32)
-    roff = np.zeros((cap,), np.int32)
-    rlen = np.zeros((cap,), np.int32)
-    rstat = np.zeros((cap,), np.int32)
-    per_name_calls: Dict[str, int] = {}
-    per_name_bytes: Dict[str, int] = {}
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-        hosts = dict(REGISTRY.hosts)
-        idem = dict(REGISTRY.idempotent)
-    drops, rdrops, cerrs, nretries = _replay_shard(
-        callee, nargs, imask, pmask, ivals, fvals, plens, pbuf, rwant, n,
-        overrides, names, hosts, per_name_calls, per_name_bytes,
-        reply=(rwords, roff, rlen, rstat), base=int(base), idem=idem,
-        retry=retry, timeout=timeout)
-    _finish_flush(drops, int(adrops), per_name_calls, per_name_bytes,
-                  reply_drops=rdrops, callee_errors=cerrs, retries=nretries)
-    return rwords, roff, rlen, rstat
-
-
-def _drain_queue_sharded(callee, nargs, imask, pmask, ivals, fvals, plens,
-                         pbuf, head, phead, adrops, base, overrides=None,
-                         retry=None, timeout=None):
-    """Host side of :meth:`ShardedRpcQueue.flush` (reply-less; v3 operand
-    tuple, no dead ``rwant`` transfer): every array carries a leading
-    device axis; records replay in ``(device, slot)`` order — device 0's
-    records first (oldest surviving to newest), then device 1's, and so on
-    — a deterministic total order over the whole mesh's records.  Each
-    shard's payloads resolve against ITS arena slice."""
-    callee, nargs, imask, pmask, ivals, fvals, plens, pbuf = (
-        np.asarray(x) for x in (callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf))
-    head = np.asarray(head)
-    adrops = np.asarray(adrops)
-    base = np.asarray(base)
-    per_name_calls: Dict[str, int] = {}
-    per_name_bytes: Dict[str, int] = {}
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-        hosts = dict(REGISTRY.hosts)
-        idem = dict(REGISTRY.idempotent)
-    drops = 0
-    total = 0
-    cerrs = 0
-    nretries = 0
-    for d in range(callee.shape[0]):
-        n = int(head[d])
-        total += n
-        sh_drops, _, sh_cerrs, sh_rr = _replay_shard(
-            callee[d], nargs[d], imask[d], pmask[d], ivals[d], fvals[d],
-            plens[d], pbuf[d], None, n, overrides, names, hosts,
-            per_name_calls, per_name_bytes, base=int(base[d]), idem=idem,
-            retry=retry, timeout=timeout)
-        drops += sh_drops
-        cerrs += sh_cerrs
-        nretries += sh_rr
-    _finish_flush(drops, int(adrops.sum()), per_name_calls, per_name_bytes,
-                  callee_errors=cerrs, retries=nretries)
-    return np.int32(total)
-
-
-def _drain_queue_sharded_replies(callee, nargs, imask, pmask, ivals, fvals,
-                                 plens, pbuf, rwant, head, phead, adrops,
-                                 base, rc, overrides=None, retry=None,
-                                 timeout=None, shard_deadline=None):
-    """Sharded two-phase flush: replay in ``(device, slot)`` order AND
-    return per-device reply state stacked along the device axis —
-    ``(rbuf (D, rc), roff (D, cap), rlen (D, cap), rstat (D, cap))``.
-    Each shard's replies pack into ITS reply buffer in the deterministic
-    replay order, so ``q.local(d).result(ticket, ...)`` reads device
-    ``d``'s results no matter how the drain interleaved the shards.
-
-    ``shard_deadline`` switches the serial per-device loop to CONCURRENT
-    per-shard workers with a shared wall-clock budget (partial-epoch
-    completion): one hung shard no longer stalls its siblings — its
-    records are stamped ``STATUS_TIMEOUT`` and everyone else's replies
-    land normally."""
-    callee, nargs, imask, pmask, ivals, fvals, plens, pbuf, rwant = (
-        np.asarray(x) for x in (callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf, rwant))
-    head = np.asarray(head)
-    adrops = np.asarray(adrops)
-    base = np.asarray(base)
-    rc = int(rc)
-    if shard_deadline is not None:
-        return _drain_sharded_replies_deadline(
-            callee, nargs, imask, pmask, ivals, fvals, plens, pbuf, rwant,
-            head, adrops, base, rc, shard_deadline, overrides, retry,
-            timeout)
-    D, cap = callee.shape[0], callee.shape[1]
-    rwords = np.zeros((D, rc), np.int32)
-    roff = np.zeros((D, cap), np.int32)
-    rlen = np.zeros((D, cap), np.int32)
-    rstat = np.zeros((D, cap), np.int32)
-    per_name_calls: Dict[str, int] = {}
-    per_name_bytes: Dict[str, int] = {}
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-        hosts = dict(REGISTRY.hosts)
-        idem = dict(REGISTRY.idempotent)
-    drops = 0
-    rdrops = 0
-    cerrs = 0
-    nretries = 0
-    for d in range(D):
-        n = int(head[d])
-        sh_drops, sh_rdrops, sh_cerrs, sh_rr = _replay_shard(
-            callee[d], nargs[d], imask[d], pmask[d], ivals[d], fvals[d],
-            plens[d], pbuf[d], rwant[d], n, overrides, names, hosts,
-            per_name_calls, per_name_bytes,
-            reply=(rwords[d], roff[d], rlen[d], rstat[d]),
-            base=int(base[d]), idem=idem, retry=retry, timeout=timeout)
-        drops += sh_drops
-        rdrops += sh_rdrops
-        cerrs += sh_cerrs
-        nretries += sh_rr
-    _finish_flush(drops, int(adrops.sum()), per_name_calls, per_name_bytes,
-                  reply_drops=rdrops, callee_errors=cerrs, retries=nretries)
-    return rwords, roff, rlen, rstat
-
-
 def _san_scan_shard(cap: int, n: int, pmask, ivals, plens, pbuf
                     ) -> Tuple[int, int, int]:
     """Verify one shard's surviving payload reservations: canaries intact on
@@ -1954,94 +1761,6 @@ def _san_record_epoch(records: int, declared: int, stomps: int, poisons: int,
             "payloads_checked": checked, "sharded": sharded})
 
 
-def _san_precheck(callee, pmask, ivals, plens, pbuf, head, rwant=None,
-                  sharded: bool = False) -> None:
-    """Host-side sanitizer pass run by the ``_san`` drain variants BEFORE the
-    replay, on the same materialized operands."""
-    pmask, ivals, plens, pbuf = (np.asarray(x)
-                                 for x in (pmask, ivals, plens, pbuf))
-    callee = np.asarray(callee)
-    head = np.asarray(head)
-    stomps = poisons = checked = records = declared = 0
-    if sharded:
-        cap = callee.shape[1]
-        for d in range(callee.shape[0]):
-            n = int(head[d])
-            s, p, c = _san_scan_shard(cap, n, pmask[d], ivals[d], plens[d],
-                                      pbuf[d])
-            stomps += s
-            poisons += p
-            checked += c
-            records += min(n, cap)
-            if rwant is not None:
-                rw = np.asarray(rwant[d])
-                lo = max(0, n - cap)
-                declared += sum(int(rw[j % cap] != 0) for j in range(lo, n))
-    else:
-        cap = callee.shape[0]
-        n = int(head)
-        stomps, poisons, checked = _san_scan_shard(cap, n, pmask, ivals,
-                                                   plens, pbuf)
-        records = min(n, cap)
-        if rwant is not None:
-            rw = np.asarray(rwant)
-            lo = max(0, n - cap)
-            declared = sum(int(rw[j % cap] != 0) for j in range(lo, n))
-    _san_record_epoch(records, declared, stomps, poisons, checked, sharded)
-
-
-def _drain_queue_san(callee, nargs, imask, pmask, ivals, fvals, plens, pbuf,
-                     head, phead, adrops, base, overrides=None, retry=None,
-                     timeout=None):
-    """Sanitized variant of :func:`_drain_queue` — same replay, preceded by
-    the canary/poison pass.  A distinct module-level callable so sanitized
-    and plain queues each hand ``io_callback`` ONE stable object."""
-    _san_precheck(callee, pmask, ivals, plens, pbuf, head)
-    return _drain_queue(callee, nargs, imask, pmask, ivals, fvals, plens,
-                        pbuf, head, phead, adrops, base,
-                        overrides=overrides, retry=retry, timeout=timeout)
-
-
-def _drain_queue_replies_san(callee, nargs, imask, pmask, ivals, fvals,
-                             plens, pbuf, rwant, head, phead, adrops, base,
-                             rc, overrides=None, retry=None, timeout=None):
-    _san_precheck(callee, pmask, ivals, plens, pbuf, head, rwant=rwant)
-    return _drain_queue_replies(callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf, rwant, head, phead, adrops,
-                                base, rc, overrides=overrides, retry=retry,
-                                timeout=timeout)
-
-
-def _drain_queue_sharded_san(callee, nargs, imask, pmask, ivals, fvals,
-                             plens, pbuf, head, phead, adrops, base,
-                             overrides=None, retry=None, timeout=None):
-    _san_precheck(callee, pmask, ivals, plens, pbuf, head, sharded=True)
-    return _drain_queue_sharded(callee, nargs, imask, pmask, ivals, fvals,
-                                plens, pbuf, head, phead, adrops, base,
-                                overrides=overrides, retry=retry,
-                                timeout=timeout)
-
-
-def _drain_queue_sharded_replies_san(callee, nargs, imask, pmask, ivals,
-                                     fvals, plens, pbuf, rwant, head, phead,
-                                     adrops, base, rc, overrides=None,
-                                     retry=None, timeout=None,
-                                     shard_deadline=None):
-    _san_precheck(callee, pmask, ivals, plens, pbuf, head, rwant=rwant,
-                  sharded=True)
-    return _drain_queue_sharded_replies(callee, nargs, imask, pmask, ivals,
-                                        fvals, plens, pbuf, rwant, head,
-                                        phead, adrops, base, rc,
-                                        overrides=overrides, retry=retry,
-                                        timeout=timeout,
-                                        shard_deadline=shard_deadline)
-
-
-# ---------------------------------------------------------------------------
-# Concurrent sharded drain (per-shard deadlines) and the v6 async transport
-# ---------------------------------------------------------------------------
-
-
 def _reserve_occurrences(inj, names_in_order):
     """Reserve per-callee occurrence indices for a concurrent/async drain,
     in its canonical ``(device, slot)`` replay order.  Returns ``None``
@@ -2061,106 +1780,6 @@ def _surviving_names(callee_row, names, n: int) -> List[Optional[str]]:
     cap = callee_row.shape[0]
     lo = max(0, n - cap)
     return [names.get(int(callee_row[j % cap])) for j in range(lo, n)]
-
-
-def _drain_sharded_replies_deadline(callee, nargs, imask, pmask, ivals,
-                                    fvals, plens, pbuf, rwant, head, adrops,
-                                    base, rc, shard_deadline, overrides,
-                                    retry, timeout):
-    """The ``shard_deadline`` branch of the sharded two-phase flush: one
-    worker thread per shard, all started together, each given the SHARED
-    wall-clock budget measured from drain start.  A shard that finishes in
-    time merges its (privately written) reply arrays and counters; a shard
-    that does not is ABANDONED — its row reads ``STATUS_TIMEOUT``, its
-    worker notices via the ``abandoned`` flag and stops early, and its
-    partial host effects stand (the same contract as a per-record
-    timeout).  Fault determinism survives the concurrency because
-    occurrence indices are reserved up front in the serial drain's
-    ``(device, slot)`` order."""
-    D, cap = callee.shape[0], callee.shape[1]
-    rwords = np.zeros((D, rc), np.int32)
-    roff = np.zeros((D, cap), np.int32)
-    rlen = np.zeros((D, cap), np.int32)
-    rstat = np.zeros((D, cap), np.int32)
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-        hosts = dict(REGISTRY.hosts)
-        idem = dict(REGISTRY.idempotent)
-    inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
-    per_dev_names = [_surviving_names(callee[d], names, int(head[d]))
-                     for d in range(D)]
-    flat = _reserve_occurrences(inj, [nm for row in per_dev_names
-                                      for nm in row])
-    occs: List[Optional[List[int]]] = [None] * D
-    if flat is not None:
-        pos = 0
-        for d in range(D):
-            occs[d] = flat[pos:pos + len(per_dev_names[d])]
-            pos += len(per_dev_names[d])
-    shard_out = [(np.zeros((rc,), np.int32), np.zeros((cap,), np.int32),
-                  np.zeros((cap,), np.int32), np.zeros((cap,), np.int32))
-                 for _ in range(D)]
-    results: List[Any] = [None] * D
-    done = [threading.Event() for _ in range(D)]
-    timed_out = [False] * D
-
-    def run(d: int) -> None:
-        pnc: Dict[str, int] = {}
-        pnb: Dict[str, int] = {}
-        try:
-            counters = _replay_shard(
-                callee[d], nargs[d], imask[d], pmask[d], ivals[d],
-                fvals[d], plens[d], pbuf[d], rwant[d], int(head[d]),
-                overrides, names, hosts, pnc, pnb, reply=shard_out[d],
-                base=int(base[d]), idem=idem, retry=retry, timeout=timeout,
-                occ=occs[d], abandoned=(lambda: timed_out[d]))
-            results[d] = (counters, pnc, pnb)
-        except BaseException as exc:  # noqa: BLE001 — relayed to coordinator
-            results[d] = exc
-        finally:
-            done[d].set()
-
-    threads = [threading.Thread(target=run, args=(d,), daemon=True,
-                                name=f"rpc-shard-drain-{d}")
-               for d in range(D)]
-    for t in threads:
-        t.start()
-    t0 = time.monotonic()
-    drops = rdrops = cerrs = nretries = stalled = 0
-    per_name_calls: Dict[str, int] = {}
-    per_name_bytes: Dict[str, int] = {}
-    for d in range(D):
-        remaining = shard_deadline - (time.monotonic() - t0)
-        if done[d].wait(max(0.0, remaining)):
-            res = results[d]
-            if isinstance(res, BaseException):
-                raise res
-            (sh_drops, sh_rdrops, sh_cerrs, sh_rr), pnc, pnb = res
-            rwords[d], roff[d], rlen[d], rstat[d] = shard_out[d]
-            drops += sh_drops
-            rdrops += sh_rdrops
-            cerrs += sh_cerrs
-            nretries += sh_rr
-            for nm, c in pnc.items():
-                per_name_calls[nm] = per_name_calls.get(nm, 0) + c
-                per_name_bytes[nm] = per_name_bytes.get(nm, 0) + pnb[nm]
-        else:
-            # partial-epoch completion: ONLY this shard's records fail;
-            # its private arrays are never merged (the late worker may
-            # still be writing them) and the whole row reads TIMEOUT
-            timed_out[d] = True
-            stalled += 1
-            rstat[d, :] = STATUS_TIMEOUT
-            cerrs += min(int(head[d]), cap)
-    if stalled:
-        warnings.warn(
-            f"RpcQueue sharded flush abandoned {stalled} shard(s) past the "
-            f"{shard_deadline}s per-shard drain deadline: their records "
-            "read STATUS_TIMEOUT while sibling shards completed "
-            "(partial-epoch completion).", RuntimeWarning, stacklevel=2)
-    _finish_flush(drops, int(adrops.sum()), per_name_calls, per_name_bytes,
-                  reply_drops=rdrops, callee_errors=cerrs, retries=nretries)
-    return rwords, roff, rlen, rstat
 
 
 #: Once-per-process latch for the CPU async-dispatch hazard warning.
@@ -2252,9 +1871,8 @@ class _QueueSlot:
     """Host-side state of one async queue lineage (allocated at
     ``create``): per-device single-thread executors (the FIFO per-shard
     epoch sequence that makes independent drains deterministically
-    replayable), the in-flight epoch jobs, the cross-epoch carry lists,
-    finalized carry outcomes, and the cache of bound drain callables (so a
-    traced flush hands ``io_callback`` a stable object)."""
+    replayable), the in-flight epoch jobs, the cross-epoch carry lists and
+    the finalized carry outcomes."""
 
     def __init__(self, sid: int):
         self.id = sid
@@ -2263,7 +1881,6 @@ class _QueueSlot:
         self.pending: Dict[int, deque] = {}
         self.carry: Dict[int, List[_CarryRec]] = {}
         self.outcomes: Dict[int, Dict[int, Tuple[int, Optional[np.ndarray]]]] = {}
-        self.drain_fns: Dict[Any, Callable] = {}
 
     # -- submit / collect ---------------------------------------------------
 
@@ -2293,11 +1910,9 @@ class _QueueSlot:
         zeros.  A ``deadline`` overrun abandons the job: fresh
         TIMEOUT-stamped arrays are returned (never the job's possibly
         still-being-written ones) and the late drain self-truncates."""
-        zeros = (np.zeros((rc,), np.int32), np.zeros((cap,), np.int32),
-                 np.zeros((cap,), np.int32), np.zeros((cap,), np.int32))
         if prev is None:
             with self.lock:
-                return zeros, len(self.carry.get(dev, ()))
+                return _reply_zeros(rc, cap), len(self.carry.get(dev, ()))
         ok = prev.done.wait(deadline) if deadline is not None else (
             prev.done.wait() or True)
         with self.lock:
@@ -2307,10 +1922,8 @@ class _QueueSlot:
             cd = (prev.cdepth if ok else len(self.carry.get(dev, ())))
         if not ok:
             prev.abandoned = True
-            stamped = (zeros[0], zeros[1], zeros[2],
-                       np.full((cap,), STATUS_TIMEOUT, np.int32))
-            return stamped, cd
-        out = prev.out if prev.out is not None else zeros
+            return _reply_zeros(rc, cap, STATUS_TIMEOUT), cd
+        out = prev.out if prev.out is not None else _reply_zeros(rc, cap)
         return out, cd
 
     def join(self, timeout: Optional[float] = None) -> bool:
@@ -2464,10 +2077,7 @@ def _run_async_epoch(slot: _QueueSlot, dev: int, job: _EpochJob, arrs,
             hosts = dict(REGISTRY.hosts)
             idem = dict(REGISTRY.idempotent)
         ccerrs, _ = _replay_carry(slot, dev, hosts, idem, overrides, timeout)
-        reply = None
-        if rc:
-            reply = (np.zeros((rc,), np.int32), np.zeros((cap,), np.int32),
-                     np.zeros((cap,), np.int32), np.zeros((cap,), np.int32))
+        reply = _reply_zeros(rc, cap) if rc else None
         sink = (_CarrySink(carry_budget)
                 if (carry_budget and rc and not job.abandoned) else None)
         drops, rdrops, cerrs, nretries = _replay_shard(
@@ -2492,222 +2102,335 @@ def _run_async_epoch(slot: _QueueSlot, dev: int, job: _EpochJob, arrs,
         job.done.set()
 
 
-def _async_flush_shard(slot: _QueueSlot, dev: int, arrs, rwant, n: int,
-                       adrops: int, base: int, rc: int, sanitize: bool,
-                       carry_budget: int, deadline: Optional[float],
-                       overrides, retry, timeout, occ):
-    """Submit one shard's epoch and collect its previous one (the
-    double-buffer hand-off).  ``arrs`` must already be this epoch's COPIES
-    — jax may reuse the callback operands' buffers after it returns."""
-    cap = arrs[0].shape[0]
-    if sanitize:
-        _san_precheck(arrs[0], arrs[3], arrs[4], arrs[6], arrs[7], n,
-                      rwant=rwant)
-    job = _EpochJob(base)
-    runner = (lambda: _run_async_epoch(
-        slot, dev, job, arrs, rwant, n, adrops, base, rc, cap,
-        carry_budget, occ, overrides, retry, timeout))
-    prev = slot.submit(dev, job, runner)
-    return slot.collect(dev, prev, deadline, cap, rc)
+# ---------------------------------------------------------------------------
+# The drain core: the host side of every queue flush
+# ---------------------------------------------------------------------------
+
+class _DrainSpec(NamedTuple):
+    """What a flush's drain does, read off the queue's static fields:
+    ``sharded`` (every leaf carries a leading device axis), ``reply``
+    (``reply_capacity > 0``: the operands carry ``rwant`` and ``rc``, and
+    the drain returns the reply quadruple) and the queue's pytree aux.
+    Hashable: it keys the cache of bound drains."""
+    sharded: bool
+    reply: bool
+    sanitize: bool
+    mode: str
+    qslot: Optional[int]
+    carry_budget: int
+    shard_deadline: Optional[float]
+    retry: Optional[RetryPolicy]
+    timeout: Optional[float]
 
 
-def _drain_queue_async_replies(slot_id: int, sanitize: bool,
-                               carry_budget: int, deadline: Optional[float],
-                               callee, nargs, imask, pmask, ivals, fvals,
-                               plens, pbuf, rwant, head, phead, adrops,
-                               base, rc, overrides=None, retry=None,
-                               timeout=None):
-    """Host side of the ASYNC two-phase flush: copy this epoch's operands,
-    submit its drain to the slot's executor, and return the PREVIOUS
-    epoch's reply quadruple plus the carried-record depth.  The device
-    installs the returned window under ``(rbase, rcount) = (pbase,
-    pcount)`` — replies land one epoch late."""
-    arrs = tuple(np.array(x) for x in (callee, nargs, imask, pmask, ivals,
-                                       fvals, plens, pbuf))
-    rwant = np.array(rwant)
-    n = int(head)
-    rc = int(rc)
-    slot = _slot(slot_id)
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-    inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
-    occ = _reserve_occurrences(inj, _surviving_names(arrs[0], names, n))
-    (rwords, roff, rlen, rstat), cdepth = _async_flush_shard(
-        slot, 0, arrs, rwant, n, int(adrops), int(base), rc, sanitize,
-        carry_budget, deadline, overrides, retry, timeout, occ)
-    return rwords, roff, rlen, rstat, np.int32(cdepth)
+def _reply_zeros(rc: int, cap: int, status: int = STATUS_OK):
+    """One shard's empty reply quadruple ``(rbuf, roff, rlen, rstat)``."""
+    return (np.zeros((rc,), np.int32), np.zeros((cap,), np.int32),
+            np.zeros((cap,), np.int32), np.full((cap,), status, np.int32))
 
 
-def _drain_queue_async(slot_id: int, sanitize: bool, carry_budget: int,
-                       deadline: Optional[float], callee, nargs, imask,
-                       pmask, ivals, fvals, plens, pbuf, head, phead,
-                       adrops, base, overrides=None, retry=None,
-                       timeout=None):
-    """Reply-less async flush: submit this epoch, wait out the previous
-    one (ordering only — there is no reply state to install), return the
-    carried depth (always 0: carry requires a reply lane)."""
-    arrs = tuple(np.array(x) for x in (callee, nargs, imask, pmask, ivals,
-                                       fvals, plens, pbuf))
-    n = int(head)
-    slot = _slot(slot_id)
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-    inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
-    occ = _reserve_occurrences(inj, _surviving_names(arrs[0], names, n))
-    _, cdepth = _async_flush_shard(
-        slot, 0, arrs, None, n, int(adrops), int(base), 0, sanitize,
-        0, deadline, overrides, retry, timeout, occ)
-    return np.int32(cdepth)
-
-
-def _drain_queue_sharded_async_replies(slot_id: int, sanitize: bool,
-                                       carry_budget: int,
-                                       deadline: Optional[float], callee,
-                                       nargs, imask, pmask, ivals, fvals,
-                                       plens, pbuf, rwant, head, phead,
-                                       adrops, base, rc, overrides=None,
-                                       retry=None, timeout=None):
-    """Sharded async flush: one epoch job per shard on per-(slot, device)
-    executors — independent drains, NO gather barrier.  Each shard's
-    previous epoch is collected under its own ``deadline`` slice
-    (partial-epoch completion: a stalled shard's rows read
-    ``STATUS_TIMEOUT`` while its siblings' replies land).  Determinism:
-    per-shard epoch sequences are FIFO on their executor, and occurrence
-    indices are reserved here in canonical ``(device, slot)`` order before
-    any job starts."""
-    arrs = tuple(np.array(x) for x in (callee, nargs, imask, pmask, ivals,
-                                       fvals, plens, pbuf))
-    rwant = np.array(rwant)
-    head = np.asarray(head)
-    adrops = np.asarray(adrops)
-    base = np.asarray(base)
-    rc = int(rc)
-    D, cap = arrs[0].shape[0], arrs[0].shape[1]
-    slot = _slot(slot_id)
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-    inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
-    per_dev_names = [_surviving_names(arrs[0][d], names, int(head[d]))
-                     for d in range(D)]
-    flat = _reserve_occurrences(inj, [nm for row in per_dev_names
-                                      for nm in row])
-    occs: List[Optional[List[int]]] = [None] * D
-    if flat is not None:
-        pos = 0
-        for d in range(D):
-            occs[d] = flat[pos:pos + len(per_dev_names[d])]
-            pos += len(per_dev_names[d])
-    rwords = np.zeros((D, rc), np.int32)
-    roff = np.zeros((D, cap), np.int32)
-    rlen = np.zeros((D, cap), np.int32)
-    rstat = np.zeros((D, cap), np.int32)
-    cdepths = np.zeros((D,), np.int32)
-    pending = []
-    for d in range(D):
-        sh_arrs = tuple(a[d] for a in arrs)
-        if sanitize:
-            _san_precheck(sh_arrs[0], sh_arrs[3], sh_arrs[4], sh_arrs[6],
-                          sh_arrs[7], int(head[d]), rwant=rwant[d])
-        job = _EpochJob(int(base[d]))
-        runner = (lambda j=job, a=sh_arrs, rw=rwant[d], nn=int(head[d]),
-                  ad=int(adrops[d]), bb=int(base[d]), oc=occs[d], dd=d:
-                  _run_async_epoch(slot, dd, j, a, rw, nn, ad, bb, rc, cap,
-                                   carry_budget, oc, overrides, retry,
-                                   timeout))
-        prev = slot.submit(d, job, runner)
-        pending.append(prev)
-    t0 = time.monotonic()
-    for d in range(D):
-        left = (None if deadline is None
-                else max(0.0, deadline - (time.monotonic() - t0)))
-        (rwords[d], roff[d], rlen[d], rstat[d]), cd = slot.collect(
-            d, pending[d], left, cap, rc)
-        cdepths[d] = cd
-    return rwords, roff, rlen, rstat, cdepths
-
-
-def _bind_async_drain(q, handlers) -> Callable:
-    """Return the drain callable for an async queue's flush, bound over
-    its slot/sanitize/carry/deadline aux (and this flush's ``handlers``).
-    Handler-less bindings are CACHED on the slot so a traced flush hands
-    ``io_callback`` a stable object (the jit cache and callback registry
-    key on callable identity)."""
-    sharded = q.callee.ndim == 2
-    if q.reply_capacity:
-        fn = (_drain_queue_sharded_async_replies if sharded
-              else _drain_queue_async_replies)
+def _san_check(spec: _DrainSpec, recs, rwant, heads: List[int]) -> None:
+    """The sanitizer pass over the epoch's surviving payloads, run before
+    any replay: one shadow record per sync flush (``sharded`` on a sharded
+    queue, its counts summed over shards), one per shard epoch for async."""
+    callee, _, _, pmask, ivals, _, plens, pbuf = recs
+    cap = callee.shape[1]
+    rows = []
+    for d, n in enumerate(heads):
+        lo = max(0, n - cap)
+        declared = (0 if rwant is None else
+                    sum(int(rwant[d, j % cap] != 0) for j in range(lo, n)))
+        rows.append((min(n, cap), declared) + _san_scan_shard(
+            cap, n, pmask[d], ivals[d], plens[d], pbuf[d]))
+    if spec.mode == "async":
+        for row in rows:
+            _san_record_epoch(*row, sharded=False)
     else:
-        fn = _drain_queue_sharded_async if sharded else _drain_queue_async
-    slot = _slot(q.qslot)
-    key = (fn.__name__, bool(q.sanitize), int(q.carry_budget),
-           q.shard_deadline, q.retry, q.timeout)
-    bound = dict(handlers) if handlers else None
-    if bound is None:
-        with slot.lock:
-            cached = slot.drain_fns.get(key)
-        if cached is not None:
-            return cached
-    sid, san, cb, dl = q.qslot, bool(q.sanitize), int(q.carry_budget), \
-        q.shard_deadline
-    retry, timeout = q.retry, q.timeout
-
-    def drain(*flat):
-        return fn(sid, san, cb, dl, *flat, overrides=bound, retry=retry,
-                  timeout=timeout)
-
-    if bound is None:
-        with slot.lock:
-            slot.drain_fns[key] = drain
-    return drain
+        _san_record_epoch(*(sum(c) for c in zip(*rows)),
+                          sharded=spec.sharded)
 
 
-def _drain_queue_sharded_async(slot_id: int, sanitize: bool,
-                               carry_budget: int, deadline: Optional[float],
-                               callee, nargs, imask, pmask, ivals, fvals,
-                               plens, pbuf, head, phead, adrops, base,
-                               overrides=None, retry=None, timeout=None):
-    """Reply-less sharded async flush (ordering + carry depth only)."""
-    arrs = tuple(np.array(x) for x in (callee, nargs, imask, pmask, ivals,
-                                       fvals, plens, pbuf))
-    head = np.asarray(head)
-    adrops = np.asarray(adrops)
-    base = np.asarray(base)
-    D, cap = arrs[0].shape[0], arrs[0].shape[1]
-    slot = _slot(slot_id)
-    with REGISTRY.lock:
-        names = dict(REGISTRY.batch_names)
-    inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
-    per_dev_names = [_surviving_names(arrs[0][d], names, int(head[d]))
-                     for d in range(D)]
-    flat = _reserve_occurrences(inj, [nm for row in per_dev_names
-                                      for nm in row])
-    occs: List[Optional[List[int]]] = [None] * D
-    if flat is not None:
-        pos = 0
-        for d in range(D):
-            occs[d] = flat[pos:pos + len(per_dev_names[d])]
-            pos += len(per_dev_names[d])
-    cdepths = np.zeros((D,), np.int32)
-    pending = []
+def _run_shards_concurrently(run: Callable, D: int, deadline: float,
+                             abandon: List[bool]) -> List[Any]:
+    """Run ``run(d)`` for every shard on its own thread, all started
+    together, under ONE wall-clock budget measured from the start.  Returns
+    each shard's result in device order; a shard past the budget yields
+    None and has its ``abandon`` flag set, so its replay stops early (its
+    partial host effects stand, its private reply arrays are never read).
+    A shard that raised re-raises here."""
+    results: List[Any] = [None] * D
+    done = [threading.Event() for _ in range(D)]
+
+    def body(d: int) -> None:
+        try:
+            results[d] = run(d)
+        except BaseException as exc:  # noqa: BLE001 — relayed to coordinator
+            results[d] = exc
+        finally:
+            done[d].set()
+
     for d in range(D):
-        sh_arrs = tuple(a[d] for a in arrs)
-        if sanitize:
-            _san_precheck(sh_arrs[0], sh_arrs[3], sh_arrs[4], sh_arrs[6],
-                          sh_arrs[7], int(head[d]))
-        job = _EpochJob(int(base[d]))
-        runner = (lambda j=job, a=sh_arrs, nn=int(head[d]),
-                  ad=int(adrops[d]), bb=int(base[d]), oc=occs[d], dd=d:
-                  _run_async_epoch(slot, dd, j, a, None, nn, ad, bb, 0, cap,
-                                   0, oc, overrides, retry, timeout))
-        prev = slot.submit(d, job, runner)
-        pending.append(prev)
+        threading.Thread(target=body, args=(d,), daemon=True,
+                         name=f"rpc-shard-drain-{d}").start()
     t0 = time.monotonic()
+    out = []
     for d in range(D):
-        left = (None if deadline is None
-                else max(0.0, deadline - (time.monotonic() - t0)))
-        _, cd = slot.collect(d, pending[d], left, cap, 0)
-        cdepths[d] = cd
-    return cdepths
+        if done[d].wait(max(0.0, deadline - (time.monotonic() - t0))):
+            if isinstance(results[d], BaseException):
+                raise results[d]
+            out.append(results[d])
+        else:
+            abandon[d] = True
+            out.append(None)
+    return out
+
+
+def _drain_sync(spec: _DrainSpec, recs, rwant, heads, bases, adrops: int,
+                rc: int, occs, overrides, registry) -> List[np.ndarray]:
+    """Replay every shard inline and return the stacked reply quadruple
+    (empty on a reply-less queue).  Shards replay serially in device
+    order, or, on a sharded queue with ``shard_deadline``, concurrently
+    under that shared budget: a shard past it reads ``STATUS_TIMEOUT``
+    while its siblings' replies land (partial-epoch completion)."""
+    D, cap = recs[0].shape[:2]
+    names, hosts, idem = registry
+    replies = [_reply_zeros(rc, cap) if spec.reply else None
+               for _ in range(D)]
+    concurrent = spec.sharded and spec.shard_deadline is not None
+    abandon = [False] * D
+
+    def run(d: int):
+        pnc: Dict[str, int] = {}
+        pnb: Dict[str, int] = {}
+        counters = _replay_shard(
+            *(a[d] for a in recs), None if rwant is None else rwant[d],
+            heads[d], overrides, names, hosts, pnc, pnb, reply=replies[d],
+            base=bases[d], idem=idem, retry=spec.retry, timeout=spec.timeout,
+            occ=occs[d],
+            abandoned=(lambda: abandon[d]) if concurrent else None)
+        return counters, pnc, pnb
+
+    results = (_run_shards_concurrently(run, D, spec.shard_deadline, abandon)
+               if concurrent else [run(d) for d in range(D)])
+    drops = rdrops = cerrs = nretries = stalled = 0
+    per_name_calls: Dict[str, int] = {}
+    per_name_bytes: Dict[str, int] = {}
+    for d, res in enumerate(results):
+        if res is None:
+            stalled += 1
+            replies[d] = _reply_zeros(rc, cap, STATUS_TIMEOUT)
+            cerrs += min(heads[d], cap)
+            continue
+        (sh_drops, sh_rdrops, sh_cerrs, sh_rr), pnc, pnb = res
+        drops += sh_drops
+        rdrops += sh_rdrops
+        cerrs += sh_cerrs
+        nretries += sh_rr
+        for nm, c in pnc.items():
+            per_name_calls[nm] = per_name_calls.get(nm, 0) + c
+            per_name_bytes[nm] = per_name_bytes.get(nm, 0) + pnb[nm]
+    if stalled:
+        warnings.warn(
+            f"RpcQueue sharded flush abandoned {stalled} shard(s) past the "
+            f"{spec.shard_deadline}s per-shard drain deadline: their "
+            "records read STATUS_TIMEOUT while sibling shards completed "
+            "(partial-epoch completion).", RuntimeWarning, stacklevel=2)
+    _finish_flush(drops, adrops, per_name_calls, per_name_bytes,
+                  reply_drops=rdrops, callee_errors=cerrs, retries=nretries)
+    return [np.stack(x) for x in zip(*replies)] if spec.reply else []
+
+
+def _drain_async(spec: _DrainSpec, recs, rwant, heads, bases, adrops, rc: int,
+                 occs, overrides) -> List[np.ndarray]:
+    """The double-buffered hand-off: SUBMIT each shard's epoch to its
+    (slot, device) executor, then COLLECT each shard's previous epoch under
+    the shared ``shard_deadline`` budget.  Returns the previous epochs'
+    stacked reply quadruple (empty on a reply-less queue) and the carried
+    depths.  Each executor is FIFO over its shard's epochs, so per-shard
+    replay order is deterministic; only the cross-shard interleaving of
+    host effects is not."""
+    D, cap = recs[0].shape[:2]
+    slot = _slot(spec.qslot)
+    prevs = []
+    for d in range(D):
+        job = _EpochJob(bases[d])
+        prevs.append(slot.submit(d, job, functools.partial(
+            _run_async_epoch, slot, d, job, tuple(a[d] for a in recs),
+            None if rwant is None else rwant[d], heads[d], int(adrops[d]),
+            bases[d], rc, cap, spec.carry_budget, occs[d], overrides,
+            spec.retry, spec.timeout)))
+    t0 = time.monotonic()
+    dl = spec.shard_deadline
+    outs = [slot.collect(d, prevs[d],
+                         None if dl is None
+                         else max(0.0, dl - (time.monotonic() - t0)),
+                         cap, rc)
+            for d in range(D)]
+    replies = ([np.stack(x) for x in zip(*(r for r, _ in outs))]
+               if spec.reply else [])
+    return replies + [np.array([cd for _, cd in outs], np.int32)]
+
+
+def _drain(spec: _DrainSpec, overrides, *flat) -> Tuple[np.ndarray, ...]:
+    """Host side of every queue flush.  ``flat`` is the flush's operand
+    tuple: the eight record lanes, then ``rwant`` on a reply queue, the
+    heads ``(head, phead, adrops)``, ``base``, then ``rc`` on a reply
+    queue.  A reply-less flush ships neither ``rwant`` nor ``rc`` — they
+    would be a dead ``(capacity,)`` device-to-host transfer on every
+    fire-and-forget flush.
+
+    A single queue drains as one shard: the leading device axis is added
+    to the host views here and dropped from the results.  Each shard's
+    records replay through :func:`_replay_shard` in ``(device, slot)``
+    order, resolving its payloads against ITS arena slice and packing its
+    replies into ITS reply buffer, under one of three schedules: serial
+    (sync), concurrent under ``shard_deadline`` (sync sharded), or
+    submitted to and collected from the slot's executors (async).  The
+    concurrent and async schedules reserve fault-occurrence indices up
+    front in the serial ``(device, slot)`` order, so faults land on the
+    same records whichever thread replays them.  ``overrides`` maps callee
+    names to the handlers a flush captured.
+
+    Returns, without the device axis on a single queue: sync — the reply
+    quadruple ``(rbuf, roff, rlen, rstat)``, or the drained record count
+    on a reply-less queue; async — the previous epoch's quadruple (reply
+    queues) and the carried depth ``cdepth``."""
+    if spec.reply:
+        *recs, rwant, head, _, adrops, base, rc = flat
+    else:
+        *recs, head, _, adrops, base = flat
+        rwant, rc = None, 0
+    # materialise to numpy ONCE so the per-record scalar indexing does not
+    # pay a device sync each time; an async epoch drains after the callback
+    # returns, when jax may reuse the operands' buffers, so it takes copies
+    take = np.array if spec.mode == "async" else np.asarray
+    recs = [take(x) for x in recs]
+    rwant = None if rwant is None else take(rwant)
+    head, adrops, base = (np.asarray(x) for x in (head, adrops, base))
+    if not spec.sharded:
+        recs = [x[None] for x in recs]
+        rwant = None if rwant is None else rwant[None]
+        head, adrops, base = head[None], adrops[None], base[None]
+    heads = [int(h) for h in head]
+    bases = [int(b) for b in base]
+    rc = int(rc)
+    if spec.sanitize:
+        _san_check(spec, recs, rwant, heads)
+    with REGISTRY.lock:                    # one snapshot, not per record
+        registry = (dict(REGISTRY.batch_names), dict(REGISTRY.hosts),
+                    dict(REGISTRY.idempotent))
+    occs: List[Optional[List[int]]] = [None] * len(heads)
+    if spec.mode == "async" or (spec.sharded
+                                and spec.shard_deadline is not None):
+        inj = _FAULT_INJECTOR[0] if _FAULT_INJECTOR else None
+        per_dev = [_surviving_names(recs[0][d], registry[0], n)
+                   for d, n in enumerate(heads)]
+        flat_occ = _reserve_occurrences(inj, [nm for row in per_dev
+                                              for nm in row])
+        if flat_occ is not None:
+            pos = 0
+            for d, row in enumerate(per_dev):
+                occs[d] = flat_occ[pos:pos + len(row)]
+                pos += len(row)
+    if spec.mode == "async":
+        outs = _drain_async(spec, recs, rwant, heads, bases, adrops, rc,
+                            occs, overrides)
+    else:
+        outs = _drain_sync(spec, recs, rwant, heads, bases,
+                           int(adrops.sum()), rc, occs, overrides, registry)
+        if not spec.reply:
+            return (np.int32(sum(heads)),)
+    return tuple(outs if spec.sharded else (x[0] for x in outs))
+
+
+_DRAINS: Dict[_DrainSpec, Callable] = {}
+_DRAINS_LOCK = threading.Lock()
+
+
+def _bind_drain(spec: _DrainSpec, handlers) -> Callable:
+    """The drain callable a flush hands ``io_callback``.  Handler-less
+    flushes share ONE cached callable per spec, so every flush of a given
+    queue geometry hands ``io_callback`` the same object (the jit cache
+    and the callback registry key on callable identity); a flush with
+    ``handlers`` captures them in its own.  The fault INJECTOR is
+    deliberately not bound: it is looked up at dispatch time, so one
+    traced program runs with and without faults."""
+    if handlers:
+        return functools.partial(_drain, spec, dict(handlers))
+    with _DRAINS_LOCK:
+        return _DRAINS.setdefault(spec, functools.partial(_drain, spec,
+                                                          None))
+
+
+def _flush(owner, handlers: Optional[Dict[str, Callable]]):
+    """The one flush behind :meth:`RpcQueue.flush` and
+    :meth:`ShardedRpcQueue.flush`: drain the epoch through ONE ordered
+    ``io_callback`` when the operands are traced — or call the drain
+    directly when a sharded queue's shards are concrete (the program
+    boundary ``device_run(mesh=)`` flushes at: XLA cannot gather
+    mesh-partitioned operands into a callback inside the partitioned
+    program) — and return the emptied queue carrying the drain's replies
+    (sync: this epoch's; async: the previous epoch's, with the epoch just
+    closed becoming the pending window)."""
+    sharded = isinstance(owner, ShardedRpcQueue)
+    q = owner.q if sharded else owner
+    records = (q.callee, q.nargs, q.imask, q.pmask, q.ivals, q.fvals,
+               q.plens, q.pbuf)
+    heads = (q.head, q.phead, q.adrops)
+    traced = any(isinstance(x, jax.core.Tracer) for x in records + heads)
+    if traced and not sharded:
+        # lazy: rpc is imported by expand's siblings at package init
+        from repro.core.expand import _ENV as _team_env_state
+        if _team_env_state.axes:
+            raise RuntimeError(
+                "RpcQueue.flush() inside a shard_map-expanded region: "
+                "XLA cannot lower the drain callback inside the "
+                "partitioned program (fatal CHECK abort).  Enqueue in "
+                "the region and flush at the program boundary — "
+                "device_run(mesh=) and ShardedRpcQueue.flush on "
+                "concrete shards do.")
+    rc = q.reply_capacity
+    cap = q.callee.shape[-1]
+    lead = q.callee.shape[:1] if sharded else ()
+    is_async = q.mode == "async"
+    spec = _DrainSpec(sharded, rc > 0, bool(q.sanitize), q.mode, q.qslot,
+                      q.carry_budget, q.shard_deadline, q.retry, q.timeout)
+    drain = _bind_drain(spec, handlers)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.int32)
+
+    if rc:
+        operands = records + (q.rwant,) + heads + (q.base, jnp.int32(rc))
+        shapes = (i32(rc), i32(cap), i32(cap), i32(cap))
+    else:
+        operands = records + heads + (q.base,)
+        shapes = ()
+    if is_async:
+        shapes += (i32(),)
+    elif not rc:
+        shapes = (jax.ShapeDtypeStruct((), jnp.int32),)
+    if traced or not sharded:
+        outs = io_callback(drain, shapes, *operands, ordered=True)
+    else:
+        outs = tuple(jnp.asarray(a) for a in drain(*operands))
+    z = jnp.zeros(lead, jnp.int32)
+    fields = dict(head=z, phead=z, adrops=z, base=q.base + q.head,
+                  fonce=jnp.ones_like(q.fonce))
+    if rc:
+        fields.update(zip(("rbuf", "roff", "rlen", "rstat"), outs))
+        fields.update(rbase=q.pbase if is_async else q.base,
+                      rcount=q.pcount if is_async else q.head)
+    if is_async:
+        fields.update(pbase=q.base, pcount=q.head, cdepth=outs[-1])
+    new_q = dataclasses.replace(q, **fields)
+    out = ShardedRpcQueue(new_q) if sharded else new_q
+    if events.active():
+        events.emit("rpc_flush", _refs=(owner, out), qid=id(q),
+                    qid_out=id(new_q), capacity=cap,
+                    payload_capacity=q.payload_capacity, reply_capacity=rc,
+                    **({"sharded": True} if sharded else {}), mode=q.mode)
+    return out
 
 
 def _payload_words(a: jax.Array) -> Tuple[jax.Array, bool]:
@@ -2723,7 +2446,7 @@ def _payload_words(a: jax.Array) -> Tuple[jax.Array, bool]:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class RpcQueue:
-    """On-device ring of pending RPC records (the batched transport, v3).
+    """On-device ring of pending RPC records (the batched transport).
 
     Each record is ``(callee id, up to W args)``.  Scalar integer args live
     in int32 lanes, scalar floats in float32 lanes, and ``imask`` bit ``j``
@@ -2738,8 +2461,7 @@ class RpcQueue:
 
     ``enqueue`` is a pure array update (zero host contact inside jit);
     ``flush`` drains every queued record AND the arena to the host in ONE
-    ordered ``io_callback``, preserving enqueue order.  Records are
-    fire-and-forget: no values return to the device.  When more than
+    ordered ``io_callback``, preserving enqueue order.  When more than
     ``capacity`` records accumulate, the oldest are overwritten (the drop
     is counted in :func:`queue_drops`; their arena words are simply never
     read — the arena is append-only between flushes, so surviving
@@ -2748,7 +2470,7 @@ class RpcQueue:
     written, the head does not advance, and the drop is counted on device
     (``adrops``) and surfaced via ``flush_stats()['arena_drops']``.
 
-    **Reply state (v4).**  A queue created with ``reply_capacity > 0``
+    **Reply state.**  A queue created with ``reply_capacity > 0``
     carries a device-resident reply table: ``rwant`` declares each slot's
     expected reply (``+words`` int32, ``-words`` float32-bitcast, 0 none —
     set by ``enqueue_ticketed(returns=...)``), and after each flush
@@ -2761,7 +2483,7 @@ class RpcQueue:
     window (stale, or from a dropped enqueue) reads zeros with
     ``ok=False``, it can never alias a later epoch's bytes.
 
-    **Async epochs (v6).**  ``create(..., mode="async")`` double-buffers
+    **Async epochs.**  ``create(..., mode="async")`` double-buffers
     the epochs: ``flush`` SUBMITS the closing epoch's drain to the
     queue's host slot and installs the PREVIOUS epoch's replies, so the
     reply window trails one epoch behind and ``pbase``/``pcount`` track
@@ -2810,7 +2532,7 @@ class RpcQueue:
     timeout: Optional[float] = None      # static: per-callee wall-clock
     #                                      deadline (seconds) at drain
     mode: str = "sync"   # static: "sync" (drain on the flush clock) or
-    #                      "async" (double-buffered epochs, v6)
+    #                      "async" (double-buffered epochs)
     qslot: Optional[int] = None  # static: host slot id of an async lineage
     carry_budget: int = 0        # static: extra cross-epoch redrive rounds
     #                              for failed idempotent records (async)
@@ -2868,9 +2590,9 @@ class RpcQueue:
         every payload between two flushes (0 = scalar-only queue: array
         args are rejected at trace time).  ``reply_capacity`` is the REPLY
         arena size in words (0 = fire-and-forget queue: ``returns=`` is
-        rejected at trace time, ``flush`` keeps the single-output callback
-        of the v3 transport, and the per-slot reply state is sized (0,) so
-        the v3 enqueue/flush hot paths carry no dead weight).
+        rejected at trace time, ``flush`` ships no reply operands, and the
+        per-slot reply state is sized (0,) so the fire-and-forget
+        enqueue/flush hot paths carry no dead weight).
 
         ``sanitize=True`` turns on the runtime sanitizer for this queue:
         every payload reservation is bracketed by :data:`CANARY` words
@@ -2887,7 +2609,7 @@ class RpcQueue:
         callee this queue drains (overrun -> ``STATUS_TIMEOUT``, drain
         continues).  Both are static queue metadata (pytree aux).
 
-        ``mode="async"`` switches to the v6 double-buffered epoch
+        ``mode="async"`` switches to the double-buffered epoch
         transport: flushes submit + collect-previous instead of draining
         inline (replies land one epoch late; see the class docstring).
         ``carry_budget`` (async, reply-carrying queues) grants failed
@@ -3123,7 +2845,7 @@ class RpcQueue:
             adrops=(self.adrops + dropped.astype(jnp.int32) if npay
                     else self.adrops),
             # reply-less queues carry (0,) reply state: no dead scatter on
-            # the v3 enqueue hot path
+            # the fire-and-forget enqueue hot path
             rwant=(self.rwant.at[i].set(rw_v) if self.rwant.shape[0]
                    else self.rwant))
         if events.active():
@@ -3154,7 +2876,7 @@ class RpcQueue:
         the reply on a later step is supported).
 
         ``handlers`` maps callee names to per-flush handlers, CAPTURED into
-        this flush's compiled program (like v1's sink closures) — records
+        this flush's compiled program — records
         for those names bypass the registry, so two compiled programs can
         drain same-named records to different handlers.  Without it, the
         drain dispatches through the registry via one stable callable.
@@ -3167,90 +2889,7 @@ class RpcQueue:
         so the failure is a Python error, not a process abort; a DIRECT
         ``jax.shard_map`` of user code bypasses the guard and still hits
         the XLA abort."""
-        records = (self.callee, self.nargs, self.imask, self.pmask,
-                   self.ivals, self.fvals, self.plens, self.pbuf)
-        heads = (self.head, self.phead, self.adrops)
-        if any(isinstance(x, jax.core.Tracer) for x in records + heads):
-            # lazy: rpc is imported by expand's siblings at package init
-            from repro.core.expand import _ENV as _team_env_state
-            if _team_env_state.axes:
-                raise RuntimeError(
-                    "RpcQueue.flush() inside a shard_map-expanded region: "
-                    "XLA cannot lower the drain callback inside the "
-                    "partitioned program (fatal CHECK abort).  Enqueue in "
-                    "the region and flush at the program boundary — "
-                    "device_run(mesh=) and ShardedRpcQueue.flush on "
-                    "concrete shards do.")
-        z = jnp.zeros((), jnp.int32)
-        one = jnp.ones_like(self.fonce)
-        rc = self.reply_capacity
-        if self.mode == "async":
-            # double-buffered epoch hand-off: SUBMIT this epoch's drain,
-            # COLLECT the previous one — the installed reply window is the
-            # PREVIOUS epoch's ((rbase, rcount) <- (pbase, pcount)) and
-            # the epoch just closed becomes the pending window
-            drain = _bind_async_drain(self, handlers)
-            if rc:
-                cap = self.capacity
-                shapes = (jax.ShapeDtypeStruct((rc,), jnp.int32),
-                          jax.ShapeDtypeStruct((cap,), jnp.int32),
-                          jax.ShapeDtypeStruct((cap,), jnp.int32),
-                          jax.ShapeDtypeStruct((cap,), jnp.int32),
-                          jax.ShapeDtypeStruct((), jnp.int32))
-                rbuf, roff, rlen, rstat, cdepth = io_callback(
-                    drain, shapes, *records, self.rwant, *heads, self.base,
-                    jnp.int32(rc), ordered=True)
-                out = dataclasses.replace(
-                    self, head=z, phead=z, adrops=z, rbuf=rbuf, roff=roff,
-                    rlen=rlen, rstat=rstat, base=self.base + self.head,
-                    rbase=self.pbase, rcount=self.pcount, pbase=self.base,
-                    pcount=self.head, cdepth=cdepth, fonce=one)
-            else:
-                cdepth = io_callback(
-                    drain, jax.ShapeDtypeStruct((), jnp.int32), *records,
-                    *heads, self.base, ordered=True)
-                out = dataclasses.replace(
-                    self, head=z, phead=z, adrops=z,
-                    base=self.base + self.head, pbase=self.base,
-                    pcount=self.head, cdepth=cdepth, fonce=one)
-            if events.active():
-                events.emit("rpc_flush", _refs=(self, out), qid=id(self),
-                            qid_out=id(out), capacity=self.capacity,
-                            payload_capacity=self.payload_capacity,
-                            reply_capacity=rc, mode="async")
-            return out
-        if rc:
-            cap = self.capacity
-            shapes = (jax.ShapeDtypeStruct((rc,), jnp.int32),
-                      jax.ShapeDtypeStruct((cap,), jnp.int32),
-                      jax.ShapeDtypeStruct((cap,), jnp.int32),
-                      jax.ShapeDtypeStruct((cap,), jnp.int32))
-            drain_fn = (_drain_queue_replies_san if self.sanitize
-                        else _drain_queue_replies)
-            rbuf, roff, rlen, rstat = io_callback(
-                _bind_drain(drain_fn, handlers, self.retry, self.timeout),
-                shapes, *records, self.rwant, *heads, self.base,
-                jnp.int32(rc), ordered=True)
-            out = dataclasses.replace(self, head=z, phead=z, adrops=z,
-                                      rbuf=rbuf, roff=roff, rlen=rlen,
-                                      rstat=rstat,
-                                      base=self.base + self.head,
-                                      rbase=self.base, rcount=self.head,
-                                      fonce=one)
-        else:
-            drain_fn = _drain_queue_san if self.sanitize else _drain_queue
-            io_callback(_bind_drain(drain_fn, handlers, self.retry,
-                                    self.timeout),
-                        jax.ShapeDtypeStruct((), jnp.int32),
-                        *records, *heads, self.base, ordered=True)
-            out = dataclasses.replace(self, head=z, phead=z, adrops=z,
-                                      base=self.base + self.head, fonce=one)
-        if events.active():
-            events.emit("rpc_flush", _refs=(self, out), qid=id(self),
-                        qid_out=id(out), capacity=self.capacity,
-                        payload_capacity=self.payload_capacity,
-                        reply_capacity=rc, mode="sync")
-        return out
+        return _flush(self, handlers)
 
     def join(self, timeout: Optional[float] = None) -> bool:
         """Async queues: block until every SUBMITTED epoch drain has
@@ -3648,119 +3287,17 @@ class ShardedRpcQueue:
         emptied sharded queue — on a reply-carrying queue, with each
         device's reply buffer/table stacked along the device axis (read
         them with :meth:`result` or ``local(d).result``)."""
-        records = (self.q.callee, self.q.nargs, self.q.imask, self.q.pmask,
-                   self.q.ivals, self.q.fvals, self.q.plens, self.q.pbuf)
-        heads = (self.q.head, self.q.phead, self.q.adrops)
-        rc = self.reply_capacity
-        D, cap = self.n_devices, self.capacity
-        z = jnp.zeros((D,), jnp.int32)
-        one = jnp.ones_like(self.q.fonce)
-        traced = any(isinstance(x, jax.core.Tracer) for x in records + heads)
-        if self.q.mode == "async":
-            # per-device INDEPENDENT drains: one epoch job per shard on
-            # the slot's per-device executors, no gather barrier — the
-            # callback returns the PREVIOUS epoch's stacked replies
-            drain = _bind_async_drain(self.q, handlers)
-            if rc:
-                operands = records + (self.q.rwant,) + heads + (self.q.base,)
-                if traced:
-                    shapes = (jax.ShapeDtypeStruct((D, rc), jnp.int32),
-                              jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                              jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                              jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                              jax.ShapeDtypeStruct((D,), jnp.int32))
-                    rbuf, roff, rlen, rstat, cdepth = io_callback(
-                        drain, shapes, *operands, jnp.int32(rc),
-                        ordered=True)
-                else:
-                    rbuf, roff, rlen, rstat, cdepth = (
-                        jnp.asarray(a) for a in drain(*operands,
-                                                      np.int32(rc)))
-                out = dataclasses.replace(self, q=dataclasses.replace(
-                    self.q, head=z, phead=z, adrops=z,
-                    rbuf=rbuf, roff=roff, rlen=rlen, rstat=rstat,
-                    base=self.q.base + self.q.head,
-                    rbase=self.q.pbase, rcount=self.q.pcount,
-                    pbase=self.q.base, pcount=self.q.head, cdepth=cdepth,
-                    fonce=one))
-            else:
-                if traced:
-                    cdepth = io_callback(
-                        drain, jax.ShapeDtypeStruct((D,), jnp.int32),
-                        *records, *heads, self.q.base, ordered=True)
-                else:
-                    cdepth = jnp.asarray(drain(*records, *heads,
-                                               self.q.base))
-                out = dataclasses.replace(self, q=dataclasses.replace(
-                    self.q, head=z, phead=z, adrops=z,
-                    base=self.q.base + self.q.head,
-                    pbase=self.q.base, pcount=self.q.head, cdepth=cdepth,
-                    fonce=one))
-            if events.active():
-                events.emit("rpc_flush", _refs=(self, out), qid=id(self.q),
-                            qid_out=id(out.q), capacity=cap,
-                            payload_capacity=self.payload_capacity,
-                            reply_capacity=rc, sharded=True, mode="async")
-            return out
-        if rc:
-            drain_fn = (_drain_queue_sharded_replies_san if self.q.sanitize
-                        else _drain_queue_sharded_replies)
-            drain = _bind_drain(drain_fn, handlers, self.q.retry,
-                                self.q.timeout, self.q.shard_deadline)
-            operands = records + (self.q.rwant,) + heads + (self.q.base,)
-            if traced:
-                shapes = (jax.ShapeDtypeStruct((D, rc), jnp.int32),
-                          jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                          jax.ShapeDtypeStruct((D, cap), jnp.int32),
-                          jax.ShapeDtypeStruct((D, cap), jnp.int32))
-                rbuf, roff, rlen, rstat = io_callback(
-                    drain, shapes, *operands, jnp.int32(rc), ordered=True)
-            else:
-                rbuf, roff, rlen, rstat = (jnp.asarray(a) for a in drain(
-                    *operands, np.int32(rc)))
-            out = dataclasses.replace(self, q=dataclasses.replace(
-                self.q, head=z, phead=z, adrops=z,
-                rbuf=rbuf, roff=roff, rlen=rlen, rstat=rstat,
-                base=self.q.base + self.q.head,
-                rbase=self.q.base, rcount=self.q.head, fonce=one))
-        else:
-            drain_fn = (_drain_queue_sharded_san if self.q.sanitize
-                        else _drain_queue_sharded)
-            drain = _bind_drain(drain_fn, handlers, self.q.retry,
-                                self.q.timeout)
-            if traced:
-                io_callback(drain, jax.ShapeDtypeStruct((), jnp.int32),
-                            *records, *heads, self.q.base, ordered=True)
-            else:
-                # concrete shards (program boundary): drain directly — this
-                # also works when the shards live on a real multi-device mesh
-                drain(*records, *heads, self.q.base)
-            out = dataclasses.replace(
-                self, q=dataclasses.replace(
-                    self.q, head=z, phead=z, adrops=z,
-                    base=self.q.base + self.q.head, fonce=one))
-        if events.active():
-            events.emit("rpc_flush", _refs=(self, out), qid=id(self.q),
-                        qid_out=id(out.q), capacity=cap,
-                        payload_capacity=self.payload_capacity,
-                        reply_capacity=rc, sharded=True, mode="sync")
-        return out
+        return _flush(self, handlers)
 
     def join(self, timeout: Optional[float] = None) -> bool:
         """Async sharded queues: wait for every shard's submitted epoch
         drains (see :meth:`RpcQueue.join`)."""
-        if self.q.qslot is None:
-            return True
-        return _slot(self.q.qslot).join(timeout)
+        return self.q.join(timeout)
 
     def carry_outcomes(self, dev: int = 0) -> Dict[int, Tuple[int, Any]]:
         """Device ``dev``'s finalized cross-epoch carry outcomes (see
         :meth:`RpcQueue.carry_outcomes`)."""
-        if self.q.qslot is None:
-            return {}
-        slot = _slot(self.q.qslot)
-        with slot.lock:
-            return dict(slot.outcomes.get(dev, {}))
+        return self.q.carry_outcomes(dev)
 
     def result(self, dev, ticket, shape=(), dtype=None) -> jax.Array:
         """Device ``dev``'s reply for ``ticket`` from the last flush (the

@@ -1378,3 +1378,125 @@ def test_adopt_manifest_requires_hosts():
         assert REGISTRY.batch_names[cid] == name
     finally:
         REGISTRY.unregister(name)
+
+
+# ---------------------------------------------------------------------------
+# The drain matrix: every queue kind through one flush and one drain core
+# ---------------------------------------------------------------------------
+
+def _flush_kind_cases():
+    cases = []
+    for shards in (1, 2):
+        for reply in (False, True):
+            for sanitize in (False, True):
+                for mode in ("sync", "async"):
+                    cases.append(pytest.param(
+                        shards, reply, sanitize, mode, None,
+                        id=f"{shards}sh-{'reply' if reply else 'noreply'}"
+                           f"-{'san' if sanitize else 'plain'}-{mode}"))
+    cases.append(pytest.param(2, True, False, "sync", 5.0,
+                              id="2sh-reply-plain-sync-deadline"))
+    return cases
+
+
+def _flush_kind_expected(shards, reply, sanitize, mode):
+    """Per-flush ``(flush_stats()['flushes'] delta, new sanitize_stats()
+    epoch records as (records, declared_replies, sharded))``, as the
+    transport recorded them before its drain variants were merged: a sync
+    flush counts once whatever its shard count, an async one once per
+    shard epoch; a sync sharded flush publishes ONE aggregated shadow
+    record, an async one a record per shard."""
+    per_shard = (2, 1 if reply else 0)
+    if not sanitize:
+        epochs = []
+    elif mode == "async":
+        epochs = [per_shard + (False,)] * shards
+    else:
+        epochs = [(per_shard[0] * shards, per_shard[1] * shards,
+                   shards > 1)]
+    return (shards if mode == "async" else 1), epochs
+
+
+@pytest.mark.parametrize("shards,reply,sanitize,mode,deadline",
+                         _flush_kind_cases())
+def test_flush_kinds(shards, reply, sanitize, mode, deadline):
+    """One case per queue kind (shard count x replies x sanitizer x mode,
+    plus the sync sharded reply queue with a per-shard deadline): a traced
+    flush hands ``io_callback`` one stable callable per geometry, a
+    reply-less flush ships no ``rwant``/``rc`` operands, every flush
+    moves ``flush_stats()``/``sanitize_stats()`` by the recorded amounts,
+    and reply tickets resolve OK through the jitted and concrete paths."""
+    from repro.core import rpc as rpc_mod
+    REGISTRY.register("fk.sink", lambda i, v: None)
+    REGISTRY.register("fk.echo", lambda x: np.int32(x) + 1)
+    kw = dict(width=2, payload_capacity=64,
+              reply_capacity=8 if reply else 0, sanitize=sanitize,
+              mode=mode, shard_deadline=deadline)
+    if shards == 1:
+        q = RpcQueue.create(8, **kw)
+    else:
+        q = ShardedRpcQueue.create(shards, 8, **kw)
+
+    def fill(q):
+        """Two records per shard — one array payload, one ticketed reply
+        on reply queues; returns the queue and each shard's ticket."""
+        views = [q] if shards == 1 else [q.local(d) for d in range(shards)]
+        tickets = []
+        for d, v in enumerate(views):
+            v = v.enqueue("fk.sink", jnp.int32(d),
+                          jnp.arange(3, dtype=jnp.int32) + d)
+            if reply:
+                v, t = v.enqueue_ticketed("fk.echo", jnp.int32(10 * d),
+                                          returns=I32)
+            else:
+                v, t = v.enqueue("fk.sink", jnp.int32(d),
+                                 jnp.zeros((2,), jnp.int32)), None
+            views[d] = v
+            tickets.append(t)
+        if shards == 1:
+            return views[0], tickets
+        return ShardedRpcQueue(jax.tree.map(lambda *xs: jnp.stack(xs),
+                                            *views)), tickets
+
+    def status(q, d, t):
+        return int(q.result_status(t) if shards == 1
+                   else q.result_status(d, t))
+
+    q, tickets = fill(q)
+    # one stable callable per geometry, and the operand tuple of its kind
+    eqns = [[e for e in jax.make_jaxpr(lambda q: q.flush())(q).jaxpr.eqns
+             if e.primitive.name == "io_callback"] for _ in range(2)]
+    assert [len(e) for e in eqns] == [1, 1]
+    (e1,), (e2,) = eqns
+    assert e1.params["callback"].callback_func is \
+        e2.params["callback"].callback_func
+    # 8 record lanes + head, phead, adrops, base; replies add rwant and rc
+    assert len(e1.invars) == (14 if reply else 12)
+
+    traces = []
+
+    @jax.jit
+    def flush(q):
+        traces.append(1)
+        return q.flush()
+
+    want = _flush_kind_expected(shards, reply, sanitize, mode)
+    seen = []
+    pending = []                 # tickets whose replies the next flush lands
+    for concrete in (False, False, True):
+        f0 = flush_stats()["flushes"]
+        s0 = len(rpc_mod.sanitize_stats()["epochs"])
+        q = q.flush() if concrete else flush(q)
+        jax.effects_barrier()
+        assert q.join()
+        seen.append((flush_stats()["flushes"] - f0,
+                     [(e["records"], e["declared_replies"], e["sharded"])
+                      for e in rpc_mod.sanitize_stats()["epochs"][s0:]]))
+        landed = tickets if mode == "sync" else pending
+        if reply:
+            for d, t in enumerate(landed):
+                assert status(q, d, t) == rpc_mod.STATUS_OK
+        pending = tickets
+        q, tickets = fill(q)
+    assert len(traces) == 1
+    assert seen == [want] * 3
